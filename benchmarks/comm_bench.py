@@ -60,12 +60,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import save_report, scale
-from repro.compat import use_mesh
+from benchmarks.common import cpu_child_env, save_report, scale
 from repro.core import boosting, metrics
 from repro.core.types import TreeConfig
 from repro.data import synthetic, tabular
 from repro.federation import compress, protocol, vfl
+from repro.launch.mesh import make_mesh
 
 PARTIES = 2
 
@@ -356,12 +356,11 @@ def main(smoke: bool = False, dataset: str | None = None) -> list:
     if len(jax.devices()) < PARTIES:
         # Another benchmark module initialized jax single-device before our
         # XLA_FLAGS hook could run (the benchmarks.run path): re-exec in a
-        # subprocess with forced host devices, same artifact either way.
+        # CPU subprocess with forced host devices, same artifact either way.
         import subprocess
         import sys
 
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        env = cpu_child_env(8)
         cmd = [sys.executable, "-m", "benchmarks.comm_bench"]
         if smoke:
             cmd.append("--smoke")
@@ -380,7 +379,7 @@ def main(smoke: bool = False, dataset: str | None = None) -> list:
         ds = synthetic.load("default_credit_card", n=n)
     x_train, d_pad = tabular.pad_features(ds.x_train, PARTIES)
     x_test, _ = tabular.pad_features(ds.x_test, PARTIES)
-    mesh = jax.make_mesh(
+    mesh = make_mesh(
         (len(jax.devices()) // PARTIES, PARTIES), ("data", "model")
     )
     tree_cfg = TreeConfig(max_depth=3, num_bins=32)
@@ -394,7 +393,7 @@ def main(smoke: bool = False, dataset: str | None = None) -> list:
         "backends": {},
     }
     n = int(x_train.shape[0])
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for name in BACKENDS:
             results["backends"][name] = run_backend(
                 name, mesh, ds, x_train, x_test, d_pad, cfg, tree_cfg

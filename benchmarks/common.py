@@ -17,6 +17,19 @@ def scale() -> str:
     return os.environ.get("REPRO_BENCH_SCALE", "quick")
 
 
+def cpu_child_env(host_devices: int | None = None) -> dict:
+    """Environment for a bench child process: pinned to the CPU backend
+    (with ``host_devices`` forced host devices when given), so a child never
+    competes with its parent for an accelerator — a chip belongs to one
+    process at a time."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    if host_devices:
+        env["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={host_devices}")
+    return env
+
+
 def save_report(name: str, payload) -> str:
     os.makedirs(REPORT_DIR, exist_ok=True)
     path = os.path.join(REPORT_DIR, f"{name}.json")

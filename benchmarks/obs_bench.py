@@ -32,7 +32,7 @@ import subprocess
 import sys
 import tempfile
 
-from benchmarks.common import save_report, scale
+from benchmarks.common import cpu_child_env, save_report, scale
 from repro.obs import log as obs_log
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -51,7 +51,7 @@ def main(smoke: bool = False) -> list:
         "--rounds", str(rounds), "--eval-every", "2",
         "--log-json", "--trace", trace_path,
     ]
-    env = dict(os.environ)
+    env = cpu_child_env()
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     proc = subprocess.run(cmd, env=env, check=True, capture_output=True,
                           text=True, cwd=ROOT)

@@ -53,10 +53,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import save_report, scale
+from benchmarks.common import cpu_child_env, save_report, scale
 from repro.core import boosting
 from repro.core import forest as forest_mod
 from repro.core.types import TreeConfig
+from repro.launch.mesh import make_mesh
 from repro.obs import trace as obs_trace
 
 #: sharded-throughput bench shape: >= 1M rows (the ISSUE floor), modest
@@ -71,12 +72,10 @@ def _sharded_child() -> None:
     """Child-process body: train vfl-histogram-sharded at >= 1M rows on a
     (4 data x 2 model) grid of forced host devices and print one JSON line
     (the parent parses stdout's last line)."""
-    from repro.compat import use_mesh
     from repro.federation import vfl
 
     data_shards, parties = SHARDED_GRID
-    mesh = jax.make_mesh((data_shards, parties), ("data", "model"),
-                         devices=jax.devices()[:data_shards * parties])
+    mesh = make_mesh((data_shards, parties), ("data", "model"))
     tree = TreeConfig(max_depth=3, num_bins=32, hist_subtraction=True)
     cfg = boosting.FedGBFConfig(
         rounds=SHARDED_ROUNDS, tree=tree, n_trees_max=2, n_trees_min=2,
@@ -89,7 +88,7 @@ def _sharded_child() -> None:
     x = jnp.asarray(rng.normal(size=(SHARDED_N, SHARDED_D)), jnp.float32)
     y = jnp.asarray(rng.integers(0, 2, SHARDED_N), jnp.float32)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.perf_counter()
         model, _ = boosting.train_fedgbf(
             x, y, cfg, jax.random.PRNGKey(0), backend=backend,
@@ -117,13 +116,9 @@ def _sharded_child() -> None:
 
 
 def _sharded_bench() -> dict:
-    """Run the >= 1M-row sharded throughput measurement in a subprocess with
-    forced host devices (the parent may already hold a 1-device jax)."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count="
-        f"{SHARDED_GRID[0] * SHARDED_GRID[1]}"
-    )
+    """Run the >= 1M-row sharded throughput measurement in a CPU subprocess
+    with forced host devices (the parent may already hold a 1-device jax)."""
+    env = cpu_child_env(SHARDED_GRID[0] * SHARDED_GRID[1])
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.train_bench", "--sharded-child"],
         env=env, check=True, capture_output=True, text=True,

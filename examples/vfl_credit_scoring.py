@@ -24,6 +24,7 @@ from repro.core import boosting, metrics
 from repro.core.types import TreeConfig
 from repro.data import synthetic, tabular
 from repro.federation import compress, secure, vfl
+from repro.launch.mesh import make_mesh
 
 if len(jax.devices()) < 2:
     raise SystemExit(
@@ -48,8 +49,7 @@ print("masked party messages (unreadable):", np.asarray(masked[0][:3]))
 print("aggregate (masks cancel):", np.asarray(secure.aggregate(masked)[:3]))
 
 # --- federated training: lossless modes + the quantized transport
-mesh = jax.make_mesh((len(jax.devices()) // PARTIES, PARTIES),
-                     ("data", "model"))
+mesh = make_mesh((len(jax.devices()) // PARTIES, PARTIES), ("data", "model"))
 tree_cfg = TreeConfig(max_depth=3, num_bins=32)
 cfg = boosting.dynamic_fedgbf_config(rounds=8, tree=tree_cfg)
 

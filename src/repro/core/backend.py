@@ -262,7 +262,7 @@ def _local_factory(**_kw) -> TreeBackend:
 
 
 def _local_pallas_factory(**_kw) -> TreeBackend:
-    # The fused training-side kernel: id/stats staging happens inside the
+    # The Pallas histogram kernel: id/stats staging happens inside the
     # kernel (kernels/histogram/train_histogram.py), not in XLA.  The child
     # variant additionally forms the subtraction pipeline's left-mask and
     # parent ids in-kernel, so the half-width pass stays staging-free too.
@@ -272,10 +272,10 @@ def _local_pallas_factory(**_kw) -> TreeBackend:
 
     return TreeBackend(
         BackendDescriptor(impl="local-pallas", histogram_impl="pallas"),
-        histogram_fn=histogram_dispatch("pallas-fused"),
-        child_histogram_fn=histogram_dispatch("pallas-fused-child"),
-        round_histogram_fn=histogram_dispatch("pallas-fused-round"),
-        round_child_histogram_fn=histogram_dispatch("pallas-fused-round-child"),
+        histogram_fn=histogram_dispatch("pallas"),
+        child_histogram_fn=histogram_dispatch("pallas-child"),
+        round_histogram_fn=histogram_dispatch("pallas-round"),
+        round_child_histogram_fn=histogram_dispatch("pallas-round-child"),
     )
 
 

@@ -5,7 +5,7 @@ VFL protocol ships between parties), so it has three implementations:
 
 * ``compute_histogram``      — portable jnp ``segment_sum`` path (default on CPU),
 * ``kernels/histogram``      — the Pallas TPU kernel (one-hot matmul on the MXU),
-  selected via ``impl="pallas"``,
+  selected via ``histogram_dispatch("pallas")``,
 * ``kernels/histogram/ref.py`` — the oracle the kernel is tested against
   (re-exports this module's function).
 
@@ -313,7 +313,7 @@ def as_child_fn(histogram_fn):
     ``histogram_fn`` runs (a shard_map collective, a quantized transport…),
     every transport's wire payload shrinks to the half-width frontier for
     free.  The Pallas training kernel has a fused variant instead
-    (``kernels/histogram/ops.compute_histogram_pallas_fused_child``) so the
+    (``kernels/histogram/ops.compute_histogram_pallas_child``) so the
     mask/halve staging never touches HBM.
     """
 
@@ -374,40 +374,29 @@ def leaf_stats(
 def histogram_dispatch(impl: str = "segment"):
     """Select a histogram implementation by name.
 
-    ``"pallas"`` is the original kernel behind an XLA staging wrapper;
-    ``"pallas-fused"`` is the training-side kernel that fuses the id/stats
-    staging into the scatter-accumulate (what ``local-pallas`` runs);
-    ``"pallas-fused-child"`` is its child-only variant for the subtraction
-    pipeline (left-mask and parent ids formed in-kernel).  The ``round-*``
-    family serves the round-native contract (DESIGN.md §9, explicit
-    (T, ...) tree axis): ``"round-segment"`` is the portable fold-the-tree-
-    into-the-segment-ids path; ``"pallas-fused-round[-child]"`` put the
-    tree on the kernel grid (what ``local-pallas``' round providers run).
+    ``"pallas"`` is the Pallas kernel (id and stats staging fused in-kernel;
+    what ``local-pallas`` runs); ``"pallas-child"`` is its child-only
+    variant for the subtraction pipeline (left-mask and parent ids formed
+    in-kernel).  The ``round-*`` family serves the round-native contract
+    (DESIGN.md §9, explicit (T, ...) tree axis): ``"round-segment"`` is the
+    portable fold-the-tree-into-the-segment-ids path; ``"pallas-round
+    [-child]"`` put the tree on the kernel grid (what ``local-pallas``'
+    round providers run).
     """
     if impl == "segment":
         return compute_histogram
     if impl == "onehot":
         return compute_histogram_onehot
-    if impl == "pallas":
-        from repro.kernels.histogram import ops as _ops
-
-        return _ops.compute_histogram_pallas
-    if impl == "pallas-fused":
-        from repro.kernels.histogram import ops as _ops
-
-        return _ops.compute_histogram_pallas_fused
-    if impl == "pallas-fused-child":
-        from repro.kernels.histogram import ops as _ops
-
-        return _ops.compute_histogram_pallas_fused_child
     if impl == "round-segment":
         return compute_round_histogram
-    if impl == "pallas-fused-round":
+    pallas = {
+        "pallas": "compute_histogram_pallas",
+        "pallas-child": "compute_histogram_pallas_child",
+        "pallas-round": "compute_round_histogram_pallas",
+        "pallas-round-child": "compute_round_histogram_pallas_child",
+    }
+    if impl in pallas:
         from repro.kernels.histogram import ops as _ops
 
-        return _ops.compute_round_histogram_pallas_fused
-    if impl == "pallas-fused-round-child":
-        from repro.kernels.histogram import ops as _ops
-
-        return _ops.compute_round_histogram_pallas_fused_child
+        return getattr(_ops, pallas[impl])
     raise ValueError(f"unknown histogram impl {impl!r}")

@@ -268,7 +268,6 @@ def probe_tree_cost(
       scaling semantics); ``grad_per_round`` is the (g, h) broadcast payload
       per passive party per round.
     """
-    from repro.compat import use_mesh
     from repro.federation import vfl  # local import: vfl imports compress
 
     num_parties = mesh.shape[mesh_roles.PARTY_AXIS]
@@ -285,7 +284,7 @@ def probe_tree_cost(
     # K-channel objectives (DESIGN.md §11) carry (n, K) derivatives; K = 1
     # keeps the historical (n,) vectors so the traced program is unchanged.
     gh_shape = (n_samples,) if n_channels == 1 else (n_samples, n_channels)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jax.eval_shape(
             backend.forest_builder,
             sds((n_samples, d), jnp.int32),
@@ -332,7 +331,6 @@ def probe_round_collectives(
 
     Returns {"counts": phase → records/trace, "totals": phase → bytes}.
     """
-    from repro.compat import use_mesh
     from repro.federation import vfl  # local import: vfl imports compress
 
     num_parties = mesh.shape[mesh_roles.PARTY_AXIS]
@@ -343,7 +341,7 @@ def probe_round_collectives(
         async_exchange=async_exchange,
     )
     sds = jax.ShapeDtypeStruct
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jax.eval_shape(
             backend.forest_builder,
             sds((n_samples, d), jnp.int32),

@@ -68,11 +68,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import use_mesh
 from repro.core import binning, boosting, forest, losses, metrics
 from repro.core import objective as objective_mod
 from repro.core.types import FedGBFConfig, TreeConfig
 from repro.federation import compress, gradientless, protocol, vfl
+from repro.launch.mesh import make_mesh
 
 
 def check(num_parties: int, aggregation: str, shard_samples: bool,
@@ -89,8 +89,7 @@ def check(num_parties: int, aggregation: str, shard_samples: bool,
     mesh_axes = ("data", "model")
     n_dev = len(jax.devices())
     data_dim = data_shards or n_dev // num_parties
-    mesh = jax.make_mesh((data_dim, num_parties), mesh_axes,
-                         devices=jax.devices()[:data_dim * num_parties])
+    mesh = make_mesh((data_dim, num_parties), mesh_axes)
 
     rng = np.random.default_rng(0)
     obj = objective_mod.get_objective(loss)
@@ -111,7 +110,7 @@ def check(num_parties: int, aggregation: str, shard_samples: bool,
         mesh, cfg, aggregation=aggregation, shard_samples=shard_samples,
         async_exchange=async_exchange,
     )
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         trees_f, pred_f = backend.build_forest(binned, g, h, smask, fmask, cfg)
 
     np.testing.assert_array_equal(
@@ -144,7 +143,7 @@ def check_no_valid_split(num_parties: int, aggregation: str, degenerate: str) ->
     single populated leaf carrying the global weight.  This is the edge the
     argmax aggregation is most exposed to (its per-party candidate exchange
     must agree on "no split" without exchanging histograms)."""
-    mesh = jax.make_mesh((1, num_parties), ("data", "model"))
+    mesh = make_mesh((1, num_parties), ("data", "model"))
 
     rng = np.random.default_rng(13)
     n, d = 256, num_parties * 2
@@ -165,7 +164,7 @@ def check_no_valid_split(num_parties: int, aggregation: str, degenerate: str) ->
     assert np.all(np.asarray(trees_c.feature) == -1), "expected a split-free tree"
 
     backend = vfl.make_vfl_backend(mesh, cfg, aggregation=aggregation)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         trees_f, pred_f = backend.build_forest(binned, g, h, smask, fmask, cfg)
 
     np.testing.assert_array_equal(
@@ -192,7 +191,7 @@ def check_topk_lossless(num_parties: int, k: int) -> None:
     """Top-k candidate pruning is lossless for ANY k >= 1: every party's own
     best candidate is in its top-k, and the party-major merge reproduces the
     centralized first-occurrence tie-break (compress.topk_choose_fn)."""
-    mesh = jax.make_mesh((1, num_parties), ("data", "model"))
+    mesh = make_mesh((1, num_parties), ("data", "model"))
     rng = np.random.default_rng(5)
     n, d = 512, num_parties * 3
     x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
@@ -208,7 +207,7 @@ def check_topk_lossless(num_parties: int, k: int) -> None:
         mesh, cfg, aggregation="argmax",
         transport=compress.TransportSpec(kind="topk", k=k),
     )
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         trees_f, pred_f = backend.build_forest(binned, g, h, smask, fmask, cfg)
     np.testing.assert_array_equal(
         np.asarray(trees_c.feature), np.asarray(trees_f.feature),
@@ -227,7 +226,7 @@ def check_goss_lossless(num_parties: int, aggregation: str) -> None:
     """GOSS is a masking policy, not a transport: the same weighted masks
     fed to the centralized and federated builders must yield bit-identical
     trees (weights ride the existing sample_mask channel)."""
-    mesh = jax.make_mesh((1, num_parties), ("data", "model"))
+    mesh = make_mesh((1, num_parties), ("data", "model"))
     rng = np.random.default_rng(11)
     n, d = 512, num_parties * 2
     x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
@@ -243,7 +242,7 @@ def check_goss_lossless(num_parties: int, aggregation: str) -> None:
 
     trees_c, pred_c = forest.build_forest(binned, g, h, smask, fmask, cfg)
     backend = vfl.make_vfl_backend(mesh, cfg, aggregation=aggregation)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         trees_f, pred_f = backend.build_forest(binned, g, h, smask, fmask, cfg)
     np.testing.assert_array_equal(
         np.asarray(trees_c.feature), np.asarray(trees_f.feature),
@@ -291,7 +290,7 @@ def check_tolerance(
     transport ON BOTH SIDES (the federated-vs-centralized contract compares
     like with like; subtraction-vs-direct has its own check).
     """
-    mesh = jax.make_mesh((1, num_parties), ("data", "model"))
+    mesh = make_mesh((1, num_parties), ("data", "model"))
     x, y = _tolerance_data(num_parties)
     cfg = FedGBFConfig(
         rounds=4, n_trees_max=3, n_trees_min=2, rho_id_min=0.5, rho_id_max=0.8,
@@ -302,7 +301,7 @@ def check_tolerance(
     backend = vfl.make_vfl_backend(
         mesh, cfg.tree, aggregation=aggregation, transport=transport
     )
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         model_f, _ = boosting.train_fedgbf(
             x, y, cfg, jax.random.PRNGKey(0), backend=backend
         )
@@ -365,7 +364,7 @@ def check_reconciliation(num_parties: int, aggregation: str, transport,
     (``n_channels=K`` widens histograms to 2K stats + count and the grad
     broadcast to 2K floats per row; DESIGN.md §11)."""
     data_dim = len(jax.devices()) // num_parties if shard_samples else 1
-    mesh = jax.make_mesh((data_dim, num_parties), ("data", "model"))
+    mesh = make_mesh((data_dim, num_parties), ("data", "model"))
     tree = TreeConfig(max_depth=max_depth, num_bins=32,
                       hist_subtraction=subtraction,
                       max_active_nodes=max_active_nodes)
@@ -473,7 +472,7 @@ def check_round_collective_counts(num_parties: int, n_trees: int,
     async backends (§10) must preserve the counts: double-buffering splits
     the transfer, never the logical message (quantized transports record 2
     per level either way: int payload + scales)."""
-    mesh = jax.make_mesh((1, num_parties), ("data", "model"))
+    mesh = make_mesh((1, num_parties), ("data", "model"))
     tree = TreeConfig(max_depth=3, num_bins=16)
     rc = compress.probe_round_collectives(
         mesh, tree, n_trees, aggregation="histogram", transport=transport,
@@ -495,7 +494,7 @@ def check_id_partition_packing(num_parties: int) -> None:
     """The bit-packed routing broadcast: measured id_partition bytes are
     the ceil(n/8) bitmap, >= 8x under the legacy 1-byte-per-row encoding
     and 32x under the int32 vector the implementation used to psum."""
-    mesh = jax.make_mesh((1, num_parties), ("data", "model"))
+    mesh = make_mesh((1, num_parties), ("data", "model"))
     tree = TreeConfig(max_depth=3, num_bins=16)
     n, d = 1536, num_parties * 2
     per_tree, _ = compress.probe_tree_cost(
@@ -517,7 +516,7 @@ def check_shared_root_tolerance(num_parties: int, bound: float = 5e-3) -> None:
     tolerance class — centralized and federated alike."""
     import dataclasses
 
-    mesh = jax.make_mesh((1, num_parties), ("data", "model"))
+    mesh = make_mesh((1, num_parties), ("data", "model"))
     x, y = _tolerance_data(num_parties)
     base = FedGBFConfig(
         rounds=4, n_trees_max=3, n_trees_min=2, rho_id_min=0.6, rho_id_max=0.9,
@@ -529,7 +528,7 @@ def check_shared_root_tolerance(num_parties: int, bound: float = 5e-3) -> None:
     model_d, _ = boosting.train_fedgbf(x, y, base, jax.random.PRNGKey(0))
     model_s, _ = boosting.train_fedgbf(x, y, shared, jax.random.PRNGKey(0))
     backend = vfl.make_vfl_backend(mesh, shared.tree, aggregation="histogram")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         model_f, _ = boosting.train_fedgbf(
             x, y, shared, jax.random.PRNGKey(0), backend=backend
         )
@@ -549,7 +548,7 @@ def check_subtraction_hist_cut(num_parties: int, transport) -> None:
     bytes must show the depth-3 cut: 7 -> 4 node-histograms per tree, i.e.
     exactly 1.75x (>= the 1.7x acceptance bar) — measured from the traced
     programs of both pipelines, not from the formulas."""
-    mesh = jax.make_mesh((1, num_parties), ("data", "model"))
+    mesh = make_mesh((1, num_parties), ("data", "model"))
     n, d = 1536, num_parties * 2
     measured = {}
     for sub in (False, True):
@@ -571,7 +570,7 @@ def check_subtraction_hist_cut(num_parties: int, transport) -> None:
 def _train_named(mesh, tcfg, cfg, x, y, backend_name, **kw):
     from repro.core.backend import get_backend
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         bk = get_backend(backend_name, mesh=mesh, tree=tcfg, **kw)
         model, _ = boosting.train_fedgbf(
             x, y, cfg, jax.random.PRNGKey(0), backend=bk, engine="scan"
@@ -589,7 +588,7 @@ def check_chaos(backend_name: str, num_parties: int = 4,
     bits of the result)."""
     from repro.federation import chaos as chaos_mod
 
-    mesh = jax.make_mesh((1, num_parties), ("data", "model"))
+    mesh = make_mesh((1, num_parties), ("data", "model"))
     tcfg = TreeConfig(max_depth=3, num_bins=16)
     cfg = FedGBFConfig(rounds=2, n_trees_max=3, n_trees_min=2,
                        rho_id_min=0.5, rho_id_max=0.8, tree=tcfg)
@@ -623,7 +622,7 @@ def check_chaos_reconciliation(aggregation: str, transport,
     ``retries`` wire phase on both the measured and predicted side."""
     from repro.federation import chaos as chaos_mod
 
-    mesh = jax.make_mesh((1, num_parties), ("data", "model"))
+    mesh = make_mesh((1, num_parties), ("data", "model"))
     tcfg = TreeConfig(max_depth=3, num_bins=16)
     cfg = FedGBFConfig(rounds=3, n_trees_max=4, n_trees_min=2,
                        rho_id_min=0.2, rho_id_max=0.5)
@@ -652,7 +651,7 @@ def check_degradation(num_parties: int = 4, n: int = 512) -> None:
     from repro.core.types import pack_ensemble
     from repro.federation import runtime
 
-    mesh = jax.make_mesh((1, num_parties), ("data", "model"))
+    mesh = make_mesh((1, num_parties), ("data", "model"))
     tcfg = TreeConfig(max_depth=3, num_bins=16)
     cfg = FedGBFConfig(rounds=4, n_trees_max=3, n_trees_min=2,
                        rho_id_min=0.5, rho_id_max=0.8, tree=tcfg)
@@ -668,7 +667,7 @@ def check_degradation(num_parties: int = 4, n: int = 512) -> None:
         "oracle needs at least one degraded (round, party); reseed"
     )
     backend = vfl.make_vfl_backend(mesh, tcfg, aggregation="histogram")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         model_f, _ = boosting.train_fedgbf(
             x, y, cfg, jax.random.PRNGKey(0), backend=backend,
             round_feature_mask=mask, engine="scan",

@@ -18,9 +18,9 @@ from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import forest as forest_mod
 from repro.core.backend import BackendDescriptor, TreeBackend, register_backend
 from repro.core.types import TreeConfig

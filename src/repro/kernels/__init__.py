@@ -1,3 +1,10 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the two hot spots: histogram accumulation
+(``histogram``) and whole-ensemble traversal (``ensemble_predict``)."""
+
+import jax
+
+
+def interpret_mode() -> bool:
+    """Pallas kernels compile for the TPU and run in interpret mode on every
+    other backend (the CPU test path); never interpreted on a TPU."""
+    return jax.default_backend() != "tpu"

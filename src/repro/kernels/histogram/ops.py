@@ -1,30 +1,22 @@
-"""Jitted wrappers for the Pallas histogram kernels.
+"""Jitted wrappers for the Pallas histogram kernel (``train_histogram.py``).
 
-Drop-in replacements for ``core.histogram.compute_histogram``:
+Drop-in replacements for the ``core.histogram`` providers:
 
-* ``compute_histogram_pallas``        — the original kernel; the wrapper
-  stages ``ids = assign * B + binned`` and ``data = stack([g*w, h*w, w])``
-  in XLA before the kernel (selected via ``histogram_dispatch("pallas")``);
-* ``compute_histogram_pallas_fused``  — the training-side fused kernel
-  (``train_histogram.py``): id fusion and stats staging happen *inside* the
-  kernel, so neither intermediate ever touches HBM (selected via
-  ``histogram_dispatch("pallas-fused")``; what the ``local-pallas`` backend
-  runs);
-* ``compute_histogram_pallas_fused_child`` — its child-only variant for the
+* ``compute_histogram_pallas`` — per-tree provider
+  (``core.histogram.compute_histogram`` contract; what the ``local-pallas``
+  backend's ``histogram_fn`` runs, ``histogram_dispatch("pallas")``);
+* ``compute_histogram_pallas_child`` — its child-only variant for the
   sibling-subtraction pipeline (DESIGN.md §6): left-mask and parent ids are
-  formed in-kernel and the one-hot contraction runs at half-frontier width
-  (``histogram_dispatch("pallas-fused-child")``; the ``local-pallas``
-  backend's ``child_histogram_fn``);
-* ``compute_round_histogram_pallas_fused[_child]`` — the round-native
-  variants (DESIGN.md §9): the tree axis is a kernel grid dimension, so ONE
-  launch accumulates the whole round's (T, nodes, d, B, 3) histogram with
-  the tree-invariant operands (binned, g, h) shared across the tree grid
-  (``histogram_dispatch("pallas-fused-round[-child]")``; what the
-  ``local-pallas`` backend's ``round_*`` providers run).
+  formed in-kernel and the one-hot contraction runs at half-frontier width;
+* ``compute_round_histogram_pallas[_child]`` — the round-native providers
+  (DESIGN.md §9): the tree axis is a kernel grid dimension, so ONE launch
+  accumulates the whole round's (T, nodes, d, B, 2K+1) histogram with the
+  tree-invariant operands (binned, g, h) shared across the tree grid.
 
-Both handle padding to tile boundaries and un-padding of the result.
-``interpret`` defaults to True off TPU so the same code paths validate on
-CPU.
+The per-tree providers are the round kernel at T = 1, so the round and
+per-tree paths are bit-identical by construction.  The wrappers do the
+layout (samples on lanes, tile padding) and undo it on the result.  The
+kernel runs compiled on TPU and in interpret mode on every other backend.
 """
 
 from __future__ import annotations
@@ -34,15 +26,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.histogram.histogram import histogram_pallas_call
-from repro.kernels.histogram.train_histogram import (
-    fused_histogram_pallas_call,
-    fused_round_histogram_pallas_call,
-)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels import interpret_mode
+from repro.kernels.histogram.train_histogram import histogram_pallas_call
 
 
 def _round_up(x: int, m: int) -> int:
@@ -50,157 +35,27 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _num_stats(g: jnp.ndarray) -> int:
-    """Stats-lane count for the derivative layout: 3 for scalar (n,) g/h,
-    2K+1 for K-channel (n, K) objectives (count stays the last lane)."""
+    """Stats-row count for the derivative layout: 3 for scalar (n,) g/h,
+    2K+1 for K-channel (n, K) objectives (count stays the last row)."""
     return 3 if g.ndim == 1 else 2 * g.shape[-1] + 1
 
 
-def _chan_pad(v: jnp.ndarray, pad_n: int) -> jnp.ndarray:
-    """Tile-pad a per-sample vector and give it an explicit channel axis:
-    (n,) -> (n_pad, 1); (n, K) -> (n_pad, K)."""
+def _chan_rows(v: jnp.ndarray, pad_n: int) -> jnp.ndarray:
+    """Per-sample derivatives as lane-major rows: (n,) -> (1, n_pad);
+    (n, K) -> (K, n_pad)."""
     v = v.astype(jnp.float32)
-    if v.ndim == 1:
-        v = v[:, None]
-    return jnp.pad(v, ((0, pad_n), (0, 0)))
-
-
-@partial(
-    jax.jit,
-    static_argnames=("num_nodes", "num_bins", "tile_n", "feat_block", "interpret"),
-)
-def compute_histogram_pallas(
-    binned: jnp.ndarray,
-    g: jnp.ndarray,
-    h: jnp.ndarray,
-    weight: jnp.ndarray,
-    assign: jnp.ndarray,
-    num_nodes: int,
-    num_bins: int,
-    *,
-    tile_n: int = 512,
-    feat_block: int = 8,
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Same contract as ``core.histogram.compute_histogram``.
-
-    Returns (num_nodes, d, num_bins, 2K+1) float32 (3 for scalar g/h).
-    """
-    if interpret is None:
-        interpret = not _on_tpu()
-    n, d = binned.shape
-    nb = num_nodes * num_bins
-    # MXU lane alignment: pad the one-hot width to 128 (see kernel docstring).
-    nb_pad = _round_up(nb, 128)
-
-    ids = assign[:, None] * num_bins + binned  # (n, d)
-    if g.ndim == 1:
-        data = jnp.stack(
-            [g * weight, h * weight, weight], axis=-1
-        ).astype(jnp.float32)  # (n, 3)
-    else:
-        w = weight[:, None]
-        data = jnp.concatenate(
-            [g * w, h * w, w], axis=-1
-        ).astype(jnp.float32)  # (n, 2K+1)
-    stats = data.shape[-1]
-    stats_pad = _round_up(stats, 8)
-
-    n_pad = _round_up(n, tile_n)
-    d_pad = _round_up(d, feat_block)
-    ids = jnp.pad(ids, ((0, n_pad - n), (0, d_pad - d)))
-    data = jnp.pad(data, ((0, n_pad - n), (0, stats_pad - stats)))
-
-    hist = histogram_pallas_call(
-        ids, data, nb_pad,
-        tile_n=tile_n, feat_block=feat_block, interpret=interpret,
-    )  # (d_pad, nb_pad, stats_pad)
-
-    hist = hist[:d, :nb, :stats]
-    return hist.reshape(d, num_nodes, num_bins, stats).transpose(1, 0, 2, 3)
+    v = v[None, :] if v.ndim == 1 else v.T
+    return jnp.pad(v, ((0, 0), (0, pad_n)))
 
 
 @partial(
     jax.jit,
     static_argnames=(
-        "num_nodes", "num_bins", "tile_n", "feat_block", "interpret", "child",
-    ),
-)
-def compute_histogram_pallas_fused(
-    binned: jnp.ndarray,
-    g: jnp.ndarray,
-    h: jnp.ndarray,
-    weight: jnp.ndarray,
-    assign: jnp.ndarray,
-    num_nodes: int,
-    num_bins: int,
-    *,
-    tile_n: int = 512,
-    feat_block: int = 8,
-    interpret: bool | None = None,
-    child: bool = False,
-) -> jnp.ndarray:
-    """Same contract as ``core.histogram.compute_histogram``, served by the
-    fused training-side kernel: no (n, d) fused-id array and no (n, 3) stats
-    stack are ever materialised — only tile-boundary zero padding happens in
-    XLA (padded rows carry weight 0, so they accumulate nothing).
-
-    With ``child=True`` it is the subtraction pipeline's child-only provider
-    (``core.histogram.as_child_fn`` semantics): ``assign`` is the current
-    level's assignment, ``num_nodes`` the PARENT count, and the left-mask /
-    parent-id staging happens in-kernel — the one-hot width (and therefore
-    the MXU contraction) shrinks to the half frontier.
-
-    Returns (num_nodes, d, num_bins, 2K+1) float32 (3 for scalar g/h).
-    """
-    if interpret is None:
-        interpret = not _on_tpu()
-    n, d = binned.shape
-    nb = num_nodes * num_bins
-    nb_pad = _round_up(nb, 128)  # MXU lane alignment (see kernel docstring)
-    stats = _num_stats(g)
-
-    n_pad = _round_up(n, tile_n)
-    d_pad = _round_up(d, feat_block)
-    pad_n = n_pad - n
-    binned_p = jnp.pad(binned, ((0, pad_n), (0, d_pad - d)))
-    assign_p = jnp.pad(assign, (0, pad_n))[:, None]
-
-    hist = fused_histogram_pallas_call(
-        binned_p, assign_p, _chan_pad(g, pad_n), _chan_pad(h, pad_n),
-        _chan_pad(weight, pad_n), nb_pad, num_bins,
-        tile_n=tile_n, feat_block=feat_block, interpret=interpret,
-        child_mode=child,
-    )  # (d_pad, nb_pad, stats_pad)
-
-    hist = hist[:d, :nb, :stats]
-    return hist.reshape(d, num_nodes, num_bins, stats).transpose(1, 0, 2, 3)
-
-
-def compute_histogram_pallas_fused_child(
-    binned: jnp.ndarray,
-    g: jnp.ndarray,
-    h: jnp.ndarray,
-    weight: jnp.ndarray,
-    assign: jnp.ndarray,
-    num_parents: int,
-    num_bins: int,
-    **kw,
-) -> jnp.ndarray:
-    """Child-only provider for ``TreeBackend.child_histogram_fn``: left-child
-    histograms at half-frontier width, all staging fused in-kernel."""
-    return compute_histogram_pallas_fused(
-        binned, g, h, weight, assign, num_parents, num_bins, child=True, **kw
-    )
-
-
-@partial(
-    jax.jit,
-    static_argnames=(
-        "num_nodes", "num_bins", "tile_n", "feat_block", "interpret", "child",
+        "num_nodes", "num_bins", "tile_n", "feat_block", "child",
         "root_delta_rows", "level",
     ),
 )
-def compute_round_histogram_pallas_fused(
+def compute_round_histogram_pallas(
     binned: jnp.ndarray,
     g: jnp.ndarray,
     h: jnp.ndarray,
@@ -211,24 +66,24 @@ def compute_round_histogram_pallas_fused(
     *,
     tile_n: int = 512,
     feat_block: int = 8,
-    interpret: bool | None = None,
     child: bool = False,
     root_delta_rows: int = 0,
     level: int = 0,
 ) -> jnp.ndarray:
     """Round-native provider (``core.histogram.compute_round_histogram``
-    contract) served by the tree-grid fused kernel: ONE kernel launch
-    accumulates all T trees' histograms, with ``binned``/``g``/``h`` blocks
-    shared across the tree grid axis (the round's trees differ only in
-    their (weight, assign) masks).
+    contract) served by the tree-grid kernel: ONE launch accumulates all T
+    trees' histograms.  Only tile-boundary zero padding happens in XLA
+    (padded samples carry weight 0, so they accumulate nothing).
 
     With ``child=True`` it is the subtraction pipeline's round child
-    provider; with ``root_delta_rows > 0`` (level 0) the shared-root
-    derivation routes through ``histogram.root_histogram_via_delta`` with
-    the per-tree fused kernel as the delta accumulator.
+    provider (``assign`` is the current level's assignment, ``num_nodes``
+    the PARENT count); with ``root_delta_rows > 0`` (level 0) the
+    shared-root derivation routes through
+    ``histogram.root_histogram_via_delta`` with the per-tree provider as
+    the delta accumulator.
 
     Args:
-      weight / assign: (T, n).
+      binned: (n, d) int32; g / h: (n,) or (n, K); weight / assign: (T, n).
     Returns:
       (T, num_nodes, d, num_bins, 2K+1) float32 (3 for scalar g/h).
     """
@@ -237,49 +92,58 @@ def compute_round_histogram_pallas_fused(
 
         return root_histogram_via_delta(
             binned, g, h, weight, num_bins, root_delta_rows,
-            base_tree_fn=compute_histogram_pallas_fused,
+            base_tree_fn=compute_histogram_pallas,
         )
-    if interpret is None:
-        interpret = not _on_tpu()
     n, d = binned.shape
     t = weight.shape[0]
     nb = num_nodes * num_bins
-    nb_pad = _round_up(nb, 128)  # MXU lane alignment (see kernel docstring)
+    nb_pad = _round_up(nb, 128)  # one-hot width on lanes
     stats = _num_stats(g)
 
     n_pad = _round_up(n, tile_n)
     d_pad = _round_up(d, feat_block)
     pad_n = n_pad - n
-    binned_p = jnp.pad(binned, ((0, pad_n), (0, d_pad - d)))
-    tree_col = lambda v: jnp.pad(v, ((0, 0), (0, pad_n)))[:, :, None]
-    assign_p = tree_col(assign)
-    w_p = tree_col(weight.astype(jnp.float32))
+    binned_t = jnp.pad(binned.T, ((0, d_pad - d), (0, pad_n)))
+    tree_rows = lambda v: jnp.pad(v, ((0, 0), (0, pad_n)))[:, None, :]
 
-    hist = fused_round_histogram_pallas_call(
-        binned_p, assign_p, _chan_pad(g, pad_n), _chan_pad(h, pad_n), w_p,
-        nb_pad, num_bins,
-        tile_n=tile_n, feat_block=feat_block, interpret=interpret,
+    hist = histogram_pallas_call(
+        binned_t, tree_rows(assign), _chan_rows(g, pad_n), _chan_rows(h, pad_n),
+        tree_rows(weight.astype(jnp.float32)), nb_pad, num_bins,
+        tile_n=tile_n, feat_block=feat_block, interpret=interpret_mode(),
         child_mode=child,
-    )  # (T, d_pad, nb_pad, stats_pad)
+    )  # (T, d_pad, stats_pad, nb_pad)
 
-    hist = hist[:, :d, :nb, :stats]
-    return hist.reshape(t, d, num_nodes, num_bins, stats).transpose(
-        0, 2, 1, 3, 4
+    hist = hist[:, :d, :stats, :nb]
+    return hist.reshape(t, d, stats, num_nodes, num_bins).transpose(
+        0, 3, 1, 4, 2
     )
 
 
-def compute_round_histogram_pallas_fused_child(
-    binned: jnp.ndarray,
-    g: jnp.ndarray,
-    h: jnp.ndarray,
-    weight: jnp.ndarray,
-    assign: jnp.ndarray,
-    num_parents: int,
-    num_bins: int,
-    **kw,
+def compute_round_histogram_pallas_child(
+    binned, g, h, weight, assign, num_parents, num_bins, **kw,
 ) -> jnp.ndarray:
     """Round child provider for ``TreeBackend.round_child_histogram_fn``:
     the whole round's left-child histograms in one tree-grid launch."""
-    return compute_round_histogram_pallas_fused(
+    return compute_round_histogram_pallas(
+        binned, g, h, weight, assign, num_parents, num_bins, child=True, **kw
+    )
+
+
+def compute_histogram_pallas(
+    binned, g, h, weight, assign, num_nodes, num_bins, **kw,
+) -> jnp.ndarray:
+    """Same contract as ``core.histogram.compute_histogram``: the round
+    kernel at T = 1.  Returns (num_nodes, d, num_bins, 2K+1) float32."""
+    return compute_round_histogram_pallas(
+        binned, g, h, weight[None], assign[None], num_nodes, num_bins, **kw
+    )[0]
+
+
+def compute_histogram_pallas_child(
+    binned, g, h, weight, assign, num_parents, num_bins, **kw,
+) -> jnp.ndarray:
+    """Child-only provider for ``TreeBackend.child_histogram_fn``: left-child
+    histograms at half-frontier width, all staging fused in-kernel."""
+    return compute_histogram_pallas(
         binned, g, h, weight, assign, num_parents, num_bins, child=True, **kw
     )

@@ -1,25 +1,35 @@
-"""Fused Pallas training-side histogram kernel (DESIGN.md §2, §4).
+"""Pallas TPU kernel: gradient-histogram accumulation as one-hot MXU matmuls.
 
-The original ``histogram.py`` kernel consumes *pre-staged* operands: the
-wrapper materialises ``ids = assign * B + binned`` (an (n, d) int32 array the
-size of the feature matrix) and ``data = stack([g*w, h*w, w])`` in XLA before
-the kernel ever runs — two extra HBM round-trips per level per tree that the
-training hot path pays at every histogram build.
+TPU adaptation (DESIGN.md §2, §4). GPU GBDTs accumulate histograms with
+atomic scatter-adds into shared memory; TPUs have neither atomics nor
+arbitrary scatter. Instead the histogram is a dense contraction
 
-This kernel fuses that staging into the scatter-accumulate itself: it reads
-the raw level inputs (``binned``, ``assign``, ``g``, ``h``, ``w``) and forms
-both the fused node×bin ids and the ``[g*w, h*w, w]`` stats rows in
-VMEM/VREGs, so the only HBM traffic is the inputs once and the histogram
-out.  The accumulation is the same one-hot MXU contraction
+    hist[f, :, :] = [g*w, h*w, w, 0...]  @  onehot(node * B + bin[f, :])^T
+                    (stats_pad x T)          (T x NB)
 
-    hist[f, :, :] += onehot(assign * B + binned[:, f])^T @ [g*w, h*w, w, 0...]
+which the MXU executes as an ordinary matmul.  The kernel reads the raw
+level inputs (``binned``, ``assign``, ``g``, ``h``, ``w``) and forms both
+the fused node×bin ids and the stats rows in VMEM/VREGs, so the only HBM
+traffic is the inputs once and the histogram out.
 
-tiled over (sample tiles, feature blocks) with the standard sequential-grid
-revisiting-accumulator pattern on the output block.
+Layout (samples on lanes, the TPU-native orientation):
 
-VMEM budget per step (tile_n=512, NB<=1024, feat_block=8, f32): binned
-512*8*4 = 16 KiB, per-sample vectors 3 * 512*4 = 6 KiB, onehot 512*1024*4 =
-2 MiB, out 8*1024*8*4 = 256 KiB — comfortably inside ~16 MiB/core VMEM.
+* ``binned`` arrives transposed, (d_pad, n_pad): a (8, tile_n) block puts
+  eight features on sublanes and ``tile_n`` (a multiple of 128) samples on
+  lanes, so every block is (8, 128)-aligned.  Per-sample vectors are
+  (1, n_pad) rows; g/h are (K, n_pad).
+* The output is (T, d_pad, stats_pad, NB): ``NB = nodes * B`` padded to a
+  multiple of 128 lanes, stats (2K+1 rounded up to 8) on sublanes.
+* Grid is (tree, feature block, sample tile) with the sample-tile axis —
+  the reduction — innermost, so each output block is revisited on
+  consecutive steps and stays resident in VMEM while it accumulates
+  (initialised at tile 0).  Tree and feature-block axes are parallel.
+* The contraction runs at ``Precision.HIGHEST``: the one-hot is exact in
+  any precision, but the default single bf16 pass would round g and h.
+
+VMEM per step (tile_n=512, NB=128, f32): binned 16 KiB, one-hot 256 KiB,
+out block 32 KiB — far inside the 16 MiB scoped default.  The one-hot grows
+with NB (depth 8, B 32: NB 4096 -> 8 MiB).
 """
 
 from __future__ import annotations
@@ -29,187 +39,68 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _stats_pad(k: int) -> int:
-    """Sublane-aligned stats width for K gradient channels: round_up(2K+1, 8)
-    (== STATS_PAD at K = 1, so the binary kernel is byte-identical)."""
+    """Sublane-aligned stats width for K gradient channels: round_up(2K+1, 8)."""
     return ((2 * k + 1 + 7) // 8) * 8
 
 
-def _fused_histogram_kernel(
+def _histogram_kernel(
     binned_ref, assign_ref, g_ref, h_ref, w_ref, out_ref,
-    *, nb: int, num_bins: int, feat_block: int, child_mode: bool = False,
+    *, num_bins: int, feat_block: int, child_mode: bool,
 ):
-    """One grid step: accumulate ``feat_block`` features for one sample tile.
+    """One grid step: accumulate ``feat_block`` features of one sample tile
+    into one tree's histogram block.
 
-    binned_ref: (tile_n, feat_block) int32 raw bin ids (NOT pre-fused);
-    assign_ref: (tile_n, 1) int32 node assignment at the current level;
-    g_ref/h_ref: (tile_n, K) float32 raw derivatives (K = 1 for scalar
-        objectives; K-channel objectives fold their channels into the
-        stats axis — the grid is unchanged, DESIGN.md §11);
-    w_ref: (tile_n, 1) float32 sample mask — padded rows carry w == 0 so
-        they contribute nothing;
-    out_ref: (feat_block, nb, stats_pad) float32 accumulated histogram,
-        stats_pad = round_up(2K+1, 8) (STATS_PAD = 8 at K = 1).
+    binned_ref: (feat_block, tile_n) int32 raw bin ids (tree-invariant);
+    assign_ref / w_ref: (1, 1, tile_n) — this tree's node ids / sample mask
+        (padded rows carry w == 0, so they contribute nothing);
+    g_ref / h_ref: (K, tile_n) float32 shared derivatives (K = 1 scalar);
+    out_ref: (1, feat_block, stats_pad, NB) float32.
 
     ``child_mode`` is the subtraction pipeline's left-child-only variant
     (DESIGN.md §6): samples routed right (odd ``assign``) are weight-masked
-    to zero and the node id halves to the parent index — both formed in
-    VREGs, like the rest of the staging, so the half-width pass adds no HBM
-    traffic.  ``nb`` is then ``num_parents * num_bins`` (half the frontier).
+    to zero and the node id halves to the parent index, both formed in
+    VREGs, so the half-width pass adds no HBM traffic.
     """
 
-    @pl.when(pl.program_id(0) == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    tile_n = binned_ref.shape[0]
-    gv = g_ref[...]  # (T, K) — K = 1 for scalar-channel objectives
-    hv = h_ref[...]
-    wv = w_ref[...]  # (T, 1)
-    assign = assign_ref[...]  # (T, 1)
-    if child_mode:
-        wv = wv * (assign % 2 == 0).astype(jnp.float32)
-        assign = assign // 2
-    # Fused stats staging: [g*w, h*w, w, 0...] built in registers, never HBM
-    # ((T, K) * (T, 1) broadcasts per channel; count stays the LAST live lane).
-    pad = out_ref.shape[-1] - (2 * gv.shape[1] + 1)
-    data = jnp.concatenate(
-        [gv * wv, hv * wv, wv, jnp.zeros((tile_n, pad), jnp.float32)],
-        axis=1,
-    )  # (T, stats_pad)
-    node = assign[:, 0]  # (T,)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tile_n, nb), 1)
-
-    def body(f, carry):
-        # Fused id staging: node * B + bin, per feature column, in registers.
-        ids_col = node * num_bins + binned_ref[:, f]  # (T,)
-        onehot = (ids_col[:, None] == iota).astype(jnp.float32)  # (T, NB)
-        acc = jax.lax.dot_general(
-            onehot, data,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (NB, STATS_PAD) on the MXU
-        out_ref[f, :, :] += acc
-        return carry
-
-    jax.lax.fori_loop(0, feat_block, body, 0)
-
-
-def fused_histogram_pallas_call(
-    binned: jnp.ndarray,
-    assign: jnp.ndarray,
-    g: jnp.ndarray,
-    h: jnp.ndarray,
-    w: jnp.ndarray,
-    nb: int,
-    num_bins: int,
-    *,
-    tile_n: int = 512,
-    feat_block: int = 8,
-    interpret: bool = False,
-    child_mode: bool = False,
-) -> jnp.ndarray:
-    """Raw pallas_call. Caller guarantees padding invariants (see ops.py):
-
-    binned (n_pad, d_pad) int32, n_pad % tile_n == 0, d_pad % feat_block == 0,
-           values in [0, num_bins); padded entries may hold any in-range bin
-           because their weight is 0.
-    assign (n_pad, 1) int32 in [0, nb // num_bins) — or, when ``child_mode``,
-           the current-level assignment in [0, 2 * nb // num_bins) (the
-           kernel halves it to parent ids and masks right-routed samples);
-           g/h (n_pad, K) float32 (K = 1 scalar objectives) and w (n_pad, 1)
-           float32 with zero rows where padded/masked.
-
-    Returns (d_pad, nb, round_up(2K+1, 8)) float32 (STATS_PAD at K = 1) —
-    K-channel objectives widen the stats (lane) axis only; the grid and
-    block structure are unchanged.
-    """
-    n_pad, d_pad = binned.shape
-    k = g.shape[1]
-    stats_pad = _stats_pad(k)
-    grid = (n_pad // tile_n, d_pad // feat_block)
-    vec_spec = pl.BlockSpec((tile_n, 1), lambda i, j: (i, 0))
-    chan_spec = pl.BlockSpec((tile_n, k), lambda i, j: (i, 0))
-
-    return pl.pallas_call(
-        functools.partial(
-            _fused_histogram_kernel,
-            nb=nb, num_bins=num_bins, feat_block=feat_block,
-            child_mode=child_mode,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_n, feat_block), lambda i, j: (i, j)),
-            vec_spec,   # assign
-            chan_spec,  # g
-            chan_spec,  # h
-            vec_spec,   # w
-        ],
-        out_specs=pl.BlockSpec((feat_block, nb, stats_pad), lambda i, j: (j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((d_pad, nb, stats_pad), jnp.float32),
-        interpret=interpret,
-    )(binned, assign, g, h, w)
-
-
-def _fused_round_histogram_kernel(
-    binned_ref, assign_ref, g_ref, h_ref, w_ref, out_ref,
-    *, nb: int, num_bins: int, feat_block: int, child_mode: bool = False,
-):
-    """One grid step of the ROUND kernel (DESIGN.md §9): accumulate
-    ``feat_block`` features of one sample tile for one TREE of the round.
-
-    The tree axis is a grid dimension, not a vmap: ``binned``/``g``/``h``
-    blocks are shared across the tree grid (a round's trees differ only in
-    their masks, eq. 4), while ``assign``/``w`` (and the output block) index
-    by the tree id.  Same fused in-VREG staging as
-    ``_fused_histogram_kernel``; ``child_mode`` is the subtraction
-    pipeline's left-child variant (left-mask + parent ids in VREGs).
-
-    binned_ref: (tile_n, feat_block) int32 (tree-invariant block);
-    assign_ref / w_ref: (1, tile_n, 1) — this tree's slice;
-    g_ref / h_ref: (tile_n, K) float32 shared derivatives (K = 1 scalar);
-    out_ref: (1, feat_block, nb, stats_pad) — this tree's histogram block,
-        stats_pad = round_up(2K+1, 8) (STATS_PAD at K = 1).
-    """
-
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    tile_n = binned_ref.shape[0]
-    gv = g_ref[...]          # (T, K)
-    hv = h_ref[...]
-    wv = w_ref[0]            # strip the tree block dim -> (T, 1)
+    k, tile_n = g_ref.shape
+    stats_pad, nb = out_ref.shape[2], out_ref.shape[3]
+    wv = w_ref[0]          # (1, T)
     assign = assign_ref[0]
     if child_mode:
         wv = wv * (assign % 2 == 0).astype(jnp.float32)
         assign = assign // 2
-    pad = out_ref.shape[-1] - (2 * gv.shape[1] + 1)
-    data = jnp.concatenate(
-        [gv * wv, hv * wv, wv, jnp.zeros((tile_n, pad), jnp.float32)],
-        axis=1,
-    )  # (T, stats_pad)
-    node = assign[:, 0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tile_n, nb), 1)
-
-    def body(f, carry):
-        ids_col = node * num_bins + binned_ref[:, f]
-        onehot = (ids_col[:, None] == iota).astype(jnp.float32)
-        acc = jax.lax.dot_general(
-            onehot, data,
-            dimension_numbers=(((0,), (0,)), ((), ())),
+    gw = g_ref[...] * wv   # (K, T)
+    hw = h_ref[...] * wv
+    # Stats rows [g_1..g_K, h_1..h_K, w, 0...] built by sublane selects
+    # (count stays the LAST live row).
+    row = jax.lax.broadcasted_iota(jnp.int32, (stats_pad, tile_n), 0)
+    data = jnp.where(row == 2 * k, wv, 0.0)
+    for c in range(k):
+        data = jnp.where(row == c, gw[c:c + 1], data)
+        data = jnp.where(row == k + c, hw[c:c + 1], data)
+    node = assign * num_bins  # (1, T)
+    iota = jax.lax.broadcasted_iota(jnp.int32, (nb, tile_n), 0)
+    for f in range(feat_block):
+        ids = node + binned_ref[f:f + 1, :]               # (1, T)
+        onehot = (ids == iota).astype(jnp.float32)        # (NB, T)
+        out_ref[0, f] += jax.lax.dot_general(
+            data, onehot,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
-        )
-        out_ref[0, f, :, :] += acc
-        return carry
-
-    jax.lax.fori_loop(0, feat_block, body, 0)
+        )  # (stats_pad, NB) on the MXU
 
 
-def fused_round_histogram_pallas_call(
-    binned: jnp.ndarray,
+def histogram_pallas_call(
+    binned_t: jnp.ndarray,
     assign: jnp.ndarray,
     g: jnp.ndarray,
     h: jnp.ndarray,
@@ -222,46 +113,47 @@ def fused_round_histogram_pallas_call(
     interpret: bool = False,
     child_mode: bool = False,
 ) -> jnp.ndarray:
-    """Raw round-kernel pallas_call. Caller guarantees padding invariants
-    (see ops.py):
+    """Raw pallas_call. Caller guarantees the padding invariants (ops.py):
 
-    binned (n_pad, d_pad) int32 shared by all trees; assign / w
-    (n_trees, n_pad, 1) per-tree; g / h (n_pad, K) float32 shared (K = 1
-    scalar objectives).  Grid is (n_trees, sample tiles, feature blocks) —
-    for a fixed (tree, feature block) the sample-tile dimension revisits the
-    output block with the standard sequential-grid accumulator pattern
-    (init at tile 0).  K-channel objectives widen only the stats lanes; the
-    grid is unchanged.
+    binned_t (d_pad, n_pad) int32 shared by all trees, d_pad % feat_block
+             == 0, n_pad % tile_n == 0, values in [0, num_bins);
+    assign / w (n_trees, 1, n_pad) int32 / float32 per tree — ``assign``
+             in [0, nb // num_bins), or with ``child_mode`` the current-level
+             assignment in [0, 2 * nb // num_bins); padded samples carry
+             w == 0;
+    g / h (K, n_pad) float32 shared (K = 1 scalar objectives).
 
-    Returns (n_trees, d_pad, nb, round_up(2K+1, 8)) float32.
+    Returns (n_trees, d_pad, round_up(2K+1, 8), nb) float32.
     """
     n_trees = assign.shape[0]
-    n_pad, d_pad = binned.shape
-    k = g.shape[1]
+    d_pad, n_pad = binned_t.shape
+    k = g.shape[0]
     stats_pad = _stats_pad(k)
-    grid = (n_trees, n_pad // tile_n, d_pad // feat_block)
-    tree_vec_spec = pl.BlockSpec((1, tile_n, 1), lambda t, i, j: (t, i, 0))
-    shared_chan_spec = pl.BlockSpec((tile_n, k), lambda t, i, j: (i, 0))
-
+    grid = (n_trees, d_pad // feat_block, n_pad // tile_n)
+    tree_row = pl.BlockSpec((1, 1, tile_n), lambda t, j, i: (t, 0, i))
+    chan_row = pl.BlockSpec((k, tile_n), lambda t, j, i: (0, i))
     return pl.pallas_call(
         functools.partial(
-            _fused_round_histogram_kernel,
-            nb=nb, num_bins=num_bins, feat_block=feat_block,
+            _histogram_kernel, num_bins=num_bins, feat_block=feat_block,
             child_mode=child_mode,
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_n, feat_block), lambda t, i, j: (i, j)),
-            tree_vec_spec,     # assign
-            shared_chan_spec,  # g
-            shared_chan_spec,  # h
-            tree_vec_spec,     # w
+            pl.BlockSpec((feat_block, tile_n), lambda t, j, i: (j, i)),
+            tree_row,   # assign
+            chan_row,   # g
+            chan_row,   # h
+            tree_row,   # w
         ],
         out_specs=pl.BlockSpec(
-            (1, feat_block, nb, stats_pad), lambda t, i, j: (t, j, 0, 0)
+            (1, feat_block, stats_pad, nb), lambda t, j, i: (t, j, 0, 0)
         ),
         out_shape=jax.ShapeDtypeStruct(
-            (n_trees, d_pad, nb, stats_pad), jnp.float32
+            (n_trees, d_pad, stats_pad, nb), jnp.float32
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(binned, assign, g, h, w)
+        name="fedgbf_histogram",
+    )(binned_t, assign, g, h, w)

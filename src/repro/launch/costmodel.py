@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import use_mesh
 from repro.launch import shapes as shapes_mod
 from repro.launch.shardings import batch_spec, cache_spec, param_spec
 from repro.models import blocks, model as model_mod
@@ -107,7 +106,7 @@ def unit_body_cost(cfg, mesh, batch: int, seq: int, kind: str,
             fn = fwd
             args, shardings = (unit_shapes, x_spec), (unit_sh, x_sh)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=shardings).lower(*args).compile()
     return _per_device_cost(compiled)
 
@@ -155,7 +154,7 @@ def decode_body_cost(cfg, mesh, batch: int, seq_len: int) -> dict:
     if cross_spec is not None:
         args.append(cross_spec)
         shardings.append(cross_sh)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = (
             jax.jit(fn, in_shardings=tuple(shardings))
             .lower(*args)
@@ -183,7 +182,7 @@ def shared_block_cost(cfg, mesh, batch: int, seq: int, kind: str) -> dict:
         )
     else:
         fn = lambda p, x: blocks.shared_attn_forward(p, x, cfg)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=(sh, x_sh)).lower(
             shapes, x_spec
         ).compile()
@@ -203,7 +202,7 @@ def shared_decode_cost(cfg, mesh, batch: int, seq_len: int) -> dict:
     )
     x_spec = _x_spec(cfg, batch, 1)
     fn = lambda p, c, x, pos: blocks.shared_attn_decode(p, x, c, pos, cfg)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(
             fn,
             in_shardings=(sh, cache_sh, batch_spec(mesh, 3, batch),

@@ -26,7 +26,6 @@ import traceback
 
 import jax
 
-from repro.compat import use_mesh
 from repro.configs import ARCH_IDS, get_config
 from repro.launch import costmodel
 from repro.launch import shapes as shapes_mod
@@ -44,6 +43,8 @@ from repro.tools import roofline as roofline_mod
 
 REPORT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                           "reports", "dryrun")
+#: The chip the production mesh is made of (a key of ``roofline.PEAKS``).
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def build_step(cfg, spec, mesh):
@@ -123,7 +124,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     t0 = time.time()
     try:
         fn, args, in_sh = build_step(cfg, spec, mesh)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, in_shardings=in_sh).lower(*args)
             t_lower = time.time() - t0
             compiled = lowered.compile()
@@ -138,7 +139,8 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
             t1 = time.time()
             total = costmodel.composite_cost(cfg, mesh, shape_name, program_cost)
             t_bodies = time.time() - t1
-        roof = roofline_mod.roofline_from_costs(total, cfg, spec, chips)
+        roof = roofline_mod.roofline_from_costs(total, cfg, spec, chips,
+                                                TARGET_DEVICE_KIND)
         report = {
             "tag": tag,
             "status": "ok",

@@ -21,15 +21,16 @@ import sys
 import jax
 import jax.numpy as jnp
 
-from repro.compat import use_mesh
 from repro.core import forest as forest_mod
 from repro.core.types import TreeConfig
 from repro.federation import vfl
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.obs import perfetto
 from repro.obs import trace as obs_trace
 from repro.tools import roofline as roofline_mod
-from repro.launch.dryrun import REPORT_DIR
+
+REPORT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
+                          "reports", "dryrun")
 
 
 def run(aggregation: str, n=150_000, d=16, n_trees=5, multi_pod=False,
@@ -37,8 +38,7 @@ def run(aggregation: str, n=150_000, d=16, n_trees=5, multi_pod=False,
         data_shards=0, async_exchange=False) -> dict:
     if data_shards:
         # explicit row-shard grid (--data-shards): data_shards x 16 parties
-        mesh = jax.make_mesh((data_shards, 16), ("data", "model"),
-                             devices=jax.devices()[:data_shards * 16])
+        mesh = make_mesh((data_shards, 16), ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     chips = mesh.devices.size
@@ -65,7 +65,7 @@ def run(aggregation: str, n=150_000, d=16, n_trees=5, multi_pod=False,
     fmask = jax.ShapeDtypeStruct((n_trees, d), bool)
 
     tracer = obs_trace.global_tracer()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         # the backend's forest_builder wraps a jit; lower via a fresh jit
         with tracer.span(f"lower[{aggregation}]", cat="dryrun",
                          args={"chips": chips, "n": n, "d": d}):

@@ -11,7 +11,6 @@ import sys
 
 import jax
 
-from repro.compat import use_mesh
 from repro.configs import ARCH_IDS, get_smoke_config
 from repro.launch import shapes as shapes_mod
 from repro.launch.dryrun import build_step
@@ -36,7 +35,7 @@ def main() -> int:
         for spec in SMOKE_SPECS:
             try:
                 fn, args, in_sh = build_step(cfg, spec, mesh)
-                with use_mesh(mesh):
+                with jax.set_mesh(mesh):
                     compiled = jax.jit(fn, in_shardings=in_sh).lower(*args).compile()
                 cost = compiled.cost_analysis()
                 if isinstance(cost, (list, tuple)):

@@ -61,6 +61,7 @@ from repro.core import boosting
 from repro.core import objective as objective_mod
 from repro.core.types import PackedEnsemble
 from repro.data import synthetic
+from repro.launch import compile_cache
 from repro.obs import metrics as obs_metrics
 
 
@@ -399,6 +400,7 @@ def score_stream(
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--checkpoint", default=None,
                     help="packed checkpoint path (checkpoint.io.save_ensemble)")

@@ -52,6 +52,7 @@ from repro.data import synthetic, tabular
 from repro.federation import chaos as chaos_mod
 from repro.federation import runtime as runtime_mod
 from repro.federation import vfl  # noqa: F401  (registers vfl-* backends)
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_mod
 from repro.obs import log as obs_log
 from repro.obs import perfetto
@@ -63,6 +64,26 @@ from repro.obs import trace as obs_trace
 VFL_BACKENDS = tuple(
     n for n in backend_mod.available_backends() if n.startswith("vfl")
 )
+
+
+MODELS = ("dynamic_fedgbf", "fedgbf", "secureboost", "federated_forest")
+NUM_BINS = 32
+
+
+def model_config(model: str, rounds: int,
+                 tree: TreeConfig) -> boosting.FedGBFConfig:
+    """The training configuration ``--model`` names (paper §4.2)."""
+    if model == "dynamic_fedgbf":
+        return boosting.dynamic_fedgbf_config(rounds, tree=tree)
+    if model == "fedgbf":
+        return boosting.FedGBFConfig(
+            rounds=rounds, tree=tree, n_trees_max=5, n_trees_min=5,
+            rho_id_min=0.3, rho_id_max=0.3)
+    if model == "secureboost":
+        return boosting.secureboost_config(rounds, tree=tree)
+    if model == "federated_forest":
+        return boosting.federated_forest_config(n_trees=rounds, tree=tree)
+    raise ValueError(f"unknown model {model!r}")
 
 
 def _merge_histories(hists: list) -> "boosting.TrainHistory":
@@ -107,12 +128,11 @@ def _stitch_models(prefix_model, models: list) -> "boosting.EnsembleModel":
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", choices=list(synthetic.DATASETS),
                     default="default_credit_card")
-    ap.add_argument("--model", choices=["dynamic_fedgbf", "fedgbf",
-                                        "secureboost", "federated_forest"],
-                    default="dynamic_fedgbf")
+    ap.add_argument("--model", choices=MODELS, default="dynamic_fedgbf")
     ap.add_argument("--rounds", type=int, default=20)
     ap.add_argument("--loss", default="logistic",
                     help="objective registry name (DESIGN.md §11): logistic, "
@@ -235,19 +255,11 @@ def main() -> None:
     obs_trace.set_global_tracer(tracer)  # checkpoint I/O etc. hang off this
 
     ds = synthetic.load(args.dataset, n=args.n or None)
-    tree = TreeConfig(max_depth=args.max_depth, num_bins=32,
+    tree = TreeConfig(max_depth=args.max_depth, num_bins=NUM_BINS,
                       hist_subtraction=args.hist_subtraction,
                       max_active_nodes=args.max_active_nodes,
                       shared_root=args.shared_root)
-    cfg = {
-        "dynamic_fedgbf": lambda: boosting.dynamic_fedgbf_config(args.rounds, tree=tree),
-        "fedgbf": lambda: boosting.FedGBFConfig(
-            rounds=args.rounds, tree=tree, n_trees_max=5, n_trees_min=5,
-            rho_id_min=0.3, rho_id_max=0.3),
-        "secureboost": lambda: boosting.secureboost_config(args.rounds, tree=tree),
-        "federated_forest": lambda: boosting.federated_forest_config(
-            n_trees=args.rounds, tree=tree),
-    }[args.model]()
+    cfg = model_config(args.model, args.rounds, tree)
     if args.sampling != "uniform":
         cfg = dataclasses.replace(cfg, sampling=args.sampling)
     if args.loss != cfg.loss:

@@ -17,9 +17,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-
-from repro.compat import get_abstract_mesh
+from jax.sharding import PartitionSpec as P, get_abstract_mesh
 
 
 def _mesh_axes() -> dict:

@@ -18,7 +18,37 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    bf16_flops: float       # FLOP/s per chip
+    int8_ops: float         # OP/s per chip
+    hbm_bytes_per_s: float  # HBM bandwidth per chip
+    hbm_bytes: float        # HBM capacity per chip
+    ici_bytes_per_s: float  # chip-to-chip interconnect per chip
+
+
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e" (system architecture):
+#: 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI.
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, int8_ops=393e12, hbm_bytes_per_s=819e9,
+        hbm_bytes=16e9, ici_bytes_per_s=1600e9 / 8,
+    ),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one chip of ``device_kind``; a kind not in ``PEAKS`` is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -98,18 +128,21 @@ class Roofline:
     collective_bytes: float   # whole-program bytes moved by collectives
     chips: int
     model_flops: float        # 6*N(_active)*D useful flops
+    device_kind: str          # key into PEAKS
 
     @property
     def compute_s(self) -> float:
-        return self.flops / (self.chips * PEAK_FLOPS_BF16)
+        return self.flops / (self.chips * peaks(self.device_kind).bf16_flops)
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / (self.chips * HBM_BW)
+        return self.hbm_bytes / (
+            self.chips * peaks(self.device_kind).hbm_bytes_per_s)
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / (self.chips * ICI_BW)
+        return self.collective_bytes / (
+            self.chips * peaks(self.device_kind).ici_bytes_per_s)
 
     @property
     def dominant(self) -> str:
@@ -146,7 +179,8 @@ def model_flops_estimate(cfg, tokens: int, kind: str) -> float:
     return per_token * n_active * tokens
 
 
-def roofline_from_costs(per_device: dict, cfg, shape_spec, chips: int) -> Roofline:
+def roofline_from_costs(per_device: dict, cfg, shape_spec, chips: int,
+                        device_kind: str) -> Roofline:
     """Build a Roofline from per-device cost dict (composite or direct)."""
     tokens = shape_spec.global_batch * (
         shape_spec.seq_len if shape_spec.kind != "decode" else 1
@@ -157,10 +191,12 @@ def roofline_from_costs(per_device: dict, cfg, shape_spec, chips: int) -> Roofli
         collective_bytes=per_device["collective_bytes"] * chips,
         chips=chips,
         model_flops=model_flops_estimate(cfg, tokens, shape_spec.kind),
+        device_kind=device_kind,
     )
 
 
-def roofline_from_compiled(compiled, cfg, shape_spec, chips: int) -> Roofline:
+def roofline_from_compiled(compiled, cfg, shape_spec, chips: int,
+                           device_kind: str) -> Roofline:
     cost = compiled.cost_analysis()
     # jax 0.8: cost_analysis() returns a dict (or list of one dict)
     if isinstance(cost, (list, tuple)):
@@ -182,4 +218,5 @@ def roofline_from_compiled(compiled, cfg, shape_spec, chips: int) -> Roofline:
         collective_bytes=float(stats.total_bytes) * chips,
         chips=chips,
         model_flops=model_flops_estimate(cfg, tokens, shape_spec.kind),
+        device_kind=device_kind,
     )

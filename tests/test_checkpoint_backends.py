@@ -22,6 +22,7 @@ from repro.checkpoint import io as ckpt_io
 from repro.core import backend as backend_mod
 from repro.core import boosting
 from repro.core.types import FedGBFConfig, PackedEnsemble, TreeConfig, pack_ensemble
+from repro.launch.mesh import make_mesh
 
 TREE = TreeConfig(max_depth=2, num_bins=8)
 CFG = FedGBFConfig(rounds=2, n_trees_max=3, n_trees_min=2,
@@ -30,7 +31,7 @@ CFG = FedGBFConfig(rounds=2, n_trees_max=3, n_trees_min=2,
 
 def _build(name):
     if name.startswith("vfl"):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         return backend_mod.get_backend(name, mesh=mesh, tree=TREE)
     return backend_mod.get_backend(name)
 
@@ -45,11 +46,9 @@ def _data(n=300, d=4, seed=0):
 
 @pytest.mark.parametrize("name", backend_mod.available_backends())
 def test_checkpoint_roundtrip_every_backend(name, tmp_path):
-    from repro.compat import use_mesh
-
     x, y, x_test = _data()
     backend = _build(name)
-    ctx = use_mesh(jax.make_mesh((1, 1), ("data", "model"))) \
+    ctx = jax.set_mesh(make_mesh((1, 1), ("data", "model"))) \
         if name.startswith("vfl") else None
     if ctx is not None:
         with ctx:

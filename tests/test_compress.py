@@ -17,6 +17,7 @@ import pytest
 from repro.core import binning, forest, losses, split
 from repro.core.types import FedGBFConfig, TreeConfig
 from repro.federation import compress, protocol, vfl
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -66,7 +67,7 @@ def test_transport_spec_validation():
 
 
 def test_transport_aggregation_mismatch_rejected():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = TreeConfig(max_depth=2, num_bins=8)
     with pytest.raises(ValueError, match="does not apply"):
         vfl.make_vfl_backend(mesh, cfg, aggregation="histogram",
@@ -81,7 +82,7 @@ def test_named_backend_rejects_conflicting_transport_kwarg():
     transport= must error rather than silently ship a different format."""
     from repro.core import backend as backend_mod
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = TreeConfig(max_depth=2, num_bins=8)
     with pytest.raises(ValueError, match="encodes transport"):
         backend_mod.get_backend("vfl-histogram-q8", mesh=mesh, tree=cfg,
@@ -109,15 +110,13 @@ def _toy_forest_inputs(n=600, d=4, num_bins=16, seed=0):
 
 
 def test_topk_bit_identical_to_centralized():
-    from repro.compat import use_mesh
-
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = TreeConfig(max_depth=3, num_bins=16)
     binned, g, h, smask, fmask = _toy_forest_inputs()
     trees_c, _ = forest.build_forest(binned, g, h, smask, fmask, cfg)
     bk = vfl.make_vfl_backend(mesh, cfg, aggregation="argmax",
                               transport=compress.TOPK)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         trees_f, _ = bk.build_forest(binned, g, h, smask, fmask, cfg)
     np.testing.assert_array_equal(np.asarray(trees_c.feature),
                                   np.asarray(trees_f.feature))
@@ -126,15 +125,13 @@ def test_topk_bit_identical_to_centralized():
 
 
 def test_quantized_backend_close_to_centralized():
-    from repro.compat import use_mesh
-
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = TreeConfig(max_depth=3, num_bins=16)
     binned, g, h, smask, fmask = _toy_forest_inputs()
     trees_c, pred_c = forest.build_forest(binned, g, h, smask, fmask, cfg)
     bk = vfl.make_vfl_backend(mesh, cfg, aggregation="histogram",
                               transport=compress.Q16)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         trees_f, pred_f = bk.build_forest(binned, g, h, smask, fmask, cfg)
     # int16 quantization at toy scale: identical structure, close leaves
     np.testing.assert_array_equal(np.asarray(trees_c.feature),
@@ -156,7 +153,7 @@ def test_quantized_backend_close_to_centralized():
 def test_probe_matches_wire_model(aggregation, transport):
     """Every collective's actual traced payload == the per-party wire-model
     formula, byte for byte (1-party mesh; multi-party in selftest.py)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = TreeConfig(max_depth=3, num_bins=16)  # hist_subtraction default ON
     n, d = 500, 4
     per_tree, grad = compress.probe_tree_cost(

@@ -49,3 +49,39 @@ def test_roofline_collective_parser():
     assert stats.bytes_by_kind["all-gather"] == 16 * 1024 * 128 * 2
     assert stats.bytes_by_kind["all-reduce"] == 256 * 4
     assert stats.bytes_by_kind["reduce-scatter"] == 512 * 2
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from the published-table entry for the device kind; an
+    unknown kind is an error, never a default."""
+    import pytest
+
+    from repro.tools.roofline import Roofline, peaks
+
+    assert peaks("TPU v5 lite").bf16_flops == 197e12
+    assert peaks("TPU v5 lite").hbm_bytes_per_s == 819e9
+    roof = Roofline(flops=197e12, hbm_bytes=0.0, collective_bytes=0.0,
+                    chips=1, model_flops=0.0, device_kind="TPU v5 lite")
+    assert roof.compute_s == 1.0
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The cache follows JAX_COMPILATION_CACHE_DIR when it is set (and sets
+    nothing), else the fixed in-checkout directory."""
+    import jax
+
+    from repro.launch import compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable()
+        assert path == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

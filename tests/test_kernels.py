@@ -7,10 +7,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.histogram import ref
-from repro.kernels.histogram.ops import (
-    compute_histogram_pallas,
-    compute_histogram_pallas_fused,
-)
+from repro.kernels.histogram.ops import compute_histogram_pallas
 
 
 def _random_case(rng, n, d, B, nodes, g_dtype):
@@ -88,11 +85,11 @@ def test_histogram_kernel_tilings(tile_n, feat_block):
     ],
 )
 def test_fused_train_histogram_kernel_matches_ref(n, d, B, nodes):
-    """The training-side fused kernel (in-kernel id + stats staging) agrees
-    with the oracle on the same sweep as the staged kernel."""
+    """The kernel (in-kernel id + stats staging) agrees with the oracle on
+    a second seed of the sweep."""
     rng = np.random.default_rng(1000 + n + d + B + nodes)
     binned, g, h, w, assign = _random_case(rng, n, d, B, nodes, jnp.float32)
-    out = compute_histogram_pallas_fused(binned, g, h, w, assign, nodes, B)
+    out = compute_histogram_pallas(binned, g, h, w, assign, nodes, B)
     expected = ref.histogram_ref(binned, g, h, w, assign, nodes, B)
     assert out.shape == (nodes, d, B, 3)
     np.testing.assert_allclose(
@@ -104,7 +101,7 @@ def test_fused_train_histogram_kernel_matches_ref(n, d, B, nodes):
 def test_fused_train_histogram_kernel_tilings(tile_n, feat_block):
     rng = np.random.default_rng(17)
     binned, g, h, w, assign = _random_case(rng, 900, 11, 32, 2, jnp.float32)
-    out = compute_histogram_pallas_fused(
+    out = compute_histogram_pallas(
         binned, g, h, w, assign, 2, 32, tile_n=tile_n, feat_block=feat_block
     )
     expected = ref.histogram_ref(binned, g, h, w, assign, 2, 32)
@@ -124,7 +121,7 @@ def test_fused_kernel_vmaps_over_trees():
     w = jnp.asarray(rng.integers(0, 2, (T, n)), jnp.float32)
     assign = jnp.asarray(rng.integers(0, nodes, (T, n)), jnp.int32)
     out = jax.vmap(
-        lambda wt, at: compute_histogram_pallas_fused(
+        lambda wt, at: compute_histogram_pallas(
             binned, g, h, wt, at, nodes, B)
     )(w, assign)
     expected = jax.vmap(
@@ -147,10 +144,10 @@ def test_onehot_identity_matches_segment_sum():
 def test_kernel_inside_tree_builder():
     """End-to-end: trees built with the Pallas histogram == segment-sum trees.
 
-    The staged kernel has no registry backend of its own, so it rides an
-    ad-hoc ``TreeBackend`` (the per-provider kwargs of the historical
-    ``build_tree`` shim are gone); ``build_round`` lifts the per-tree
-    provider over the tree axis itself."""
+    Only the per-tree provider rides an ad-hoc ``TreeBackend``, so
+    ``build_round`` lifts it over the tree axis itself (vmap of the
+    kernel), unlike ``local-pallas`` whose round providers put the tree on
+    the kernel grid."""
     from repro.core import tree
     from repro.core.backend import BackendDescriptor, TreeBackend
     from repro.core.histogram import histogram_dispatch
@@ -166,7 +163,7 @@ def test_kernel_inside_tree_builder():
     fm = jnp.ones(d, bool)
 
     bk = TreeBackend(
-        BackendDescriptor(impl="adhoc-pallas-staged", histogram_impl="pallas"),
+        BackendDescriptor(impl="adhoc-pallas-per-tree", histogram_impl="pallas"),
         histogram_fn=histogram_dispatch("pallas"),
     )
     t_ref, a_ref = tree.build_tree(binned, g, h, w, fm, cfg)
@@ -186,8 +183,8 @@ def test_kernel_inside_tree_builder():
 # round (tree-grid) kernel
 # ---------------------------------------------------------------------------
 from repro.kernels.histogram.ops import (  # noqa: E402
-    compute_round_histogram_pallas_fused,
-    compute_round_histogram_pallas_fused_child,
+    compute_round_histogram_pallas,
+    compute_round_histogram_pallas_child,
 )
 
 
@@ -210,7 +207,7 @@ def test_round_kernel_matches_round_ref(n, d, B, nodes, T):
     h = jnp.asarray(rng.random(n) + 0.05, jnp.float32)
     w = jnp.asarray(rng.integers(0, 2, (T, n)), jnp.float32)
     assign = jnp.asarray(rng.integers(0, nodes, (T, n)), jnp.int32)
-    out = compute_round_histogram_pallas_fused(binned, g, h, w, assign, nodes, B)
+    out = compute_round_histogram_pallas(binned, g, h, w, assign, nodes, B)
     ref = compute_round_histogram(binned, g, h, w, assign, nodes, B)
     assert out.shape == (T, nodes, d, B, 3)
     np.testing.assert_allclose(
@@ -230,7 +227,7 @@ def test_round_child_kernel_matches_adapted_ref():
     h = jnp.asarray(rng.random(n) + 0.05, jnp.float32)
     w = jnp.asarray(rng.integers(0, 2, (T, n)), jnp.float32)
     assign = jnp.asarray(rng.integers(0, 2 * parents, (T, n)), jnp.int32)
-    out = compute_round_histogram_pallas_fused_child(
+    out = compute_round_histogram_pallas_child(
         binned, g, h, w, assign, parents, B
     )
     ref = as_round_child_fn(compute_round_histogram)(

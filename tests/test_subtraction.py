@@ -88,7 +88,7 @@ def test_derive_sibling_matches_direct_frontier(parents):
 def test_child_providers_agree():
     """Generic adaptation of every formulation + the fused Pallas child
     kernel compute the same left-child histogram."""
-    from repro.kernels.histogram.ops import compute_histogram_pallas_fused_child
+    from repro.kernels.histogram.ops import compute_histogram_pallas_child
 
     n, d, B, parents = 700, 9, 16, 4
     binned, g, h, w, assign = _case(5, n, d, B, 2 * parents)
@@ -96,7 +96,7 @@ def test_child_providers_agree():
     oh = as_child_fn(compute_histogram_onehot)(
         binned, g, h, w, assign, parents, B
     )
-    pal = compute_histogram_pallas_fused_child(
+    pal = compute_histogram_pallas_child(
         binned, g, h, w, assign, parents, B
     )
     np.testing.assert_allclose(np.asarray(oh), np.asarray(ref),

@@ -31,6 +31,7 @@ from repro.core import backend as backend_mod
 from repro.core import binning, dynamic
 from repro.core import forest as forest_mod
 from repro.core import objective as objective_mod
+from repro.obs import compiles as compiles_mod
 from repro.obs import trace as trace_mod
 from repro.core.types import (
     EnsembleModel,
@@ -53,18 +54,23 @@ class TrainHistory:
 
     ``wall_time_s`` granularity: the loop engine times every round on the
     host, so its entries are per-round exact.  The scan engine runs all
-    rounds inside ONE compiled program; it measures true PER-SEGMENT walls
-    via in-program host ticks (``jax.debug.callback`` at the segment
-    boundaries) and smears each segment's wall uniformly over its rounds —
-    per-round resolution inside a segment is fundamentally unavailable
-    without a per-round host sync, which the engine exists to avoid.
-    ``segments`` records the measured boundaries: one dict per segment
-    (``width``, ``first_round`` 0-based, ``rounds``, ``root_delta_rows``,
-    ``wall_s``, absolute host-clock ``t0``/``t1``) for the scan engine, one
-    single-round entry per round for the loop engine.  ``overhead_s`` is
-    the scan call's wall outside the segment ticks (trace + compile +
-    dispatch + history fetch) so ``sum(wall_time_s) + overhead_s``
-    reconstructs the full call.
+    rounds inside ONE compiled program.  Under a tracer that records
+    (``Tracer.records``: ``train_fedgbf --trace`` / ``--log-json``) the
+    program carries in-program host ticks (``jax.debug.callback`` at the
+    segment boundaries), so it measures true PER-SEGMENT walls and smears
+    each segment's wall uniformly over its rounds — per-round resolution
+    inside a segment is fundamentally unavailable without a per-round host
+    sync, which the engine exists to avoid.  Otherwise (the default) the
+    program holds no host callback, and the call's whole wall, trace and
+    compile included, is smeared uniformly over every round.
+    ``segments`` records the boundaries: one dict per segment (``width``,
+    ``first_round`` 0-based, ``rounds``, ``root_delta_rows``, ``wall_s``,
+    absolute host-clock ``t0``/``t1``; measured with ticks, uniform
+    shares without) for the scan engine, one single-round entry per round
+    for the loop engine.  ``overhead_s`` is the scan call's wall outside
+    the segment ticks (trace + compile + dispatch + history fetch) so
+    ``sum(wall_time_s) + overhead_s`` reconstructs the full call; 0 without
+    ticks.
 
     ``telemetry`` (filled when training runs with ``telemetry=True``) holds
     the in-graph per-round stats fetched in the engine's single host sync:
@@ -133,13 +139,17 @@ def train_fedgbf(
     (static-shape scanned engine, the default) or ``"loop"`` (legacy
     per-round reference).  Both drive the same ``TreeBackend``.
 
-    ``tracer`` (an ``obs.trace.Tracer``; None falls back to the process
-    global, default disabled) records host-side spans — binning, the
-    scan-program call, per-segment/per-round execution.  ``telemetry=True``
+    ``tracer`` (an ``obs.trace`` tracer; None falls back to the process
+    global, default disabled) takes host-side spans — binning, the
+    scan-program call, the history fetch, model assembly; a recording
+    ``Tracer`` also gets per-segment/per-round execution spans, for which
+    the scan program compiles in its segment ticks.  ``telemetry=True
     additionally threads the in-graph telemetry block through the training
     program (``TrainHistory.telemetry``); it is a jit-STATIC flag, so the
     default path compiles the exact same program as before (the 1-compile
     property and its cost are untouched — gated by benchmarks/ci_guard.py).
+    The process's compile counter (``obs.compiles``) is installed on the
+    first call.
 
     Fault tolerance (DESIGN.md §13):
 
@@ -183,6 +193,7 @@ def train_fedgbf(
             )
     if tracer is None:
         tracer = trace_mod.global_tracer()
+    compiles_mod.install()
     if engine == "scan":
         return _train_scanned(
             x, y, cfg, rng, x_valid, y_valid, backend, eval_every, verbose,
@@ -387,9 +398,11 @@ def _train_loop(
 
 #: host-side segment-boundary timestamps appended by the in-program
 #: ``jax.debug.callback`` ticks of the CURRENT scan-engine call: (seg_idx,
-#: perf_counter).  Cleared by ``_train_scanned`` before each program call and
-#: read back after ``jax.effects_barrier()`` — a probing device like
-#: ``MessageMeter``, not re-entrant across concurrent trains in one process.
+#: perf_counter).  Filled only when the program was compiled with ticks (a
+#: recording tracer).  Cleared by ``_train_scanned`` before each program
+#: call and read back after ``jax.effects_barrier()`` — a probing device
+#: like ``MessageMeter``, not re-entrant across concurrent trains in one
+#: process.
 _SEGMENT_TICKS: list = []
 
 
@@ -407,9 +420,11 @@ def _emit_tick(seg_idx: int, anchor) -> None:
     on a multi-device mesh — sequencing comes from the carry chain instead
     (tick i+1's operand depends on everything tick i's did), and the reader
     dedups per segment index.  One scalar rides per tick — a handful of
-    tiny host callbacks per *program execution*, which is why the
-    per-segment wall-time fix costs nothing measurable (ci_guard's
-    traced-vs-untraced gate).
+    tiny host callbacks per *program execution*.  Their cost is not the
+    run time but the program's identity: a host callback embeds a Python
+    pointer in the compiled module, so a program with ticks gets a fresh
+    persistent-cache key in every process and compiles anew.  Hence ticks
+    are compiled in only for a tracer that records them.
     """
     jax.debug.callback(_segment_tick, seg_idx, anchor.ravel()[0])
 
@@ -495,12 +510,12 @@ def _plan_segments(cfg: FedGBFConfig, n: int, start_round: int = 0,
 
 
 @partial(jax.jit, static_argnames=("cfg", "bk", "eval_every", "telemetry",
-                                   "start_round", "stop_round"))
+                                   "start_round", "stop_round", "ticks"))
 def _scan_train_program(
     binned, y, binned_valid, y_valid, rng, cfg: FedGBFConfig, bk,
     eval_every: int, telemetry: bool = False, round_mask=None,
     init_margin=None, init_margin_valid=None, start_round: int = 0,
-    stop_round: Optional[int] = None,
+    stop_round: Optional[int] = None, ticks: bool = False,
 ):
     """The ONE compiled training program of the scanned engine.
 
@@ -526,13 +541,20 @@ def _scan_train_program(
     None, telemetry matrix (M, max_depth + 2) or None); gated-off rounds
     hold NaN metric rows.
 
-    Observability (DESIGN.md §12): an ordered ``jax.debug.callback`` tick
-    fires at every segment boundary (anchored on the boosting carry) so the
-    caller recovers TRUE per-segment walls from one program execution; with
-    the jit-STATIC ``telemetry`` flag the per-round liveness block
-    (``_round_telemetry``) rides the scan ``ys`` and is fetched in the same
-    single host sync as the metrics — neither path adds a host round-trip
-    or a second compile.
+    Observability (DESIGN.md §12): with the jit-STATIC ``ticks`` flag (set
+    by ``_train_scanned`` only for a recording tracer) an unordered
+    ``jax.debug.callback`` tick (``_emit_tick``) fires at every segment
+    boundary, anchored on the boosting carry, so the caller recovers TRUE
+    per-segment walls from one program execution; the default program
+    holds no host callback, so the persistent compilation cache can keep
+    it.  With the jit-STATIC ``telemetry`` flag the per-round liveness
+    block (``_round_telemetry``) rides the scan ``ys`` and is fetched in
+    the same single host sync as the metrics.  Every phase of a round runs
+    under a ``jax.named_scope`` (``fedgbf.sample``, ``.grad``,
+    ``.histogram``, ``.exchange``, ``.split``, ``.route``, ``.leaf``,
+    ``.update``, ``.eval``) inside its segment's ``fedgbf.segment.T<width>``
+    scope: op metadata only, which a profiler capture reports per device
+    op; the arithmetic is unchanged.
 
     Top-level + jitted so a) it is the unit the compile-count benchmark
     inspects via ``_cache_size()``, and b) identical shapes/configs across
@@ -573,59 +595,66 @@ def _scan_train_program(
     do_eval = (rounds_idx % eval_every == 0) | (rounds_idx == cfg.rounds)
 
     # -- all mask keys up front ----------------------------------------------
-    round_keys = []
-    for _ in range(cfg.rounds):  # the loop's exact stream: one split per round
-        rng, k_round = jax.random.split(rng)
-        round_keys.append(k_round)
-    round_keys = jnp.stack(round_keys)  # (M, 2)
-    step_keys = jax.vmap(jax.random.fold_in)(
-        round_keys[jnp.asarray(flat.round_of_step)],
-        jnp.asarray(flat.tree_in_round),
-    )  # (S, 2) — prefix-stable per-slot keys, identical to the loop's
-    if not use_goss:
-        # Uniform masks depend only on the keys: one batched draw up front.
-        # GOSS masks depend on the round's gradients, so they are drawn
-        # inside round_body from the same per-slot keys instead.
-        smask_all, fmask_all = forest_mod.masks_from_keys(
-            step_keys, n, d, jnp.asarray(n_keep), d_keep
-        )  # (S, n) float32, (S, d) bool
+    with jax.named_scope("fedgbf.sample"):
+        round_keys = []
+        for _ in range(cfg.rounds):  # the loop's exact stream: one split
+            rng, k_round = jax.random.split(rng)  # per round
+            round_keys.append(k_round)
+        round_keys = jnp.stack(round_keys)  # (M, 2)
+        step_keys = jax.vmap(jax.random.fold_in)(
+            round_keys[jnp.asarray(flat.round_of_step)],
+            jnp.asarray(flat.tree_in_round),
+        )  # (S, 2) — prefix-stable per-slot keys, identical to the loop's
+        if not use_goss:
+            # Uniform masks depend only on the keys: one batched draw up
+            # front.  GOSS masks depend on the round's gradients, so they
+            # are drawn inside round_body from the same per-slot keys.
+            smask_all, fmask_all = forest_mod.masks_from_keys(
+                step_keys, n, d, jnp.asarray(n_keep), d_keep
+            )  # (S, n) float32, (S, d) bool
 
     def round_body(rdr, carry, xs):
         y_hat, y_hat_valid = carry
-        g, h = obj.grad_hess(y32, y_hat)
-        if use_goss:
-            smask, fmask = forest_mod.goss_masks_from_keys(
-                xs["keys"], g, d, xs["n_top"], xs["n_rand"], d_keep
-            )
-        else:
-            smask, fmask = xs["smask"], xs["fmask"]
-        if round_mask is not None:
-            # party-dropout degradation (DESIGN.md §13): the round's
-            # surviving columns AND into the per-tree sampled masks
-            fmask = fmask & xs["rmask"][None, :]
+        with jax.named_scope("fedgbf.grad"):
+            g, h = obj.grad_hess(y32, y_hat)
+        with jax.named_scope("fedgbf.sample"):
+            if use_goss:
+                smask, fmask = forest_mod.goss_masks_from_keys(
+                    xs["keys"], g, d, xs["n_top"], xs["n_rand"], d_keep
+                )
+            else:
+                smask, fmask = xs["smask"], xs["fmask"]
+            if round_mask is not None:
+                # party-dropout degradation (DESIGN.md §13): the round's
+                # surviving columns AND into the per-tree sampled masks
+                fmask = fmask & xs["rmask"][None, :]
         trees, per_pred = bk.build_forest_per_tree(
             binned, g, h, smask, fmask, cfg.tree, root_delta_rows=rdr
         )
-        y_hat = y_hat + lr * jnp.mean(per_pred, axis=0)
-        tele_vec = (jnp.stack(_round_telemetry(trees, smask, g,
-                                               cfg.tree.max_depth))
-                    if telemetry else None)
-        tr_vec = jax.lax.cond(
-            xs["do_eval"],
-            lambda m: obj.metric_vector(y32, m),
-            lambda m: nan_vec,
-            y_hat,
-        )
-        va_vec = nan_vec
-        if has_valid:
-            vp = tree_mod.predict_trees(trees, binned_valid, cfg.tree.max_depth)
-            y_hat_valid = y_hat_valid + lr * jnp.mean(vp, axis=0)
-            va_vec = jax.lax.cond(
+        with jax.named_scope("fedgbf.update"):
+            y_hat = y_hat + lr * jnp.mean(per_pred, axis=0)
+        with jax.named_scope("fedgbf.eval"):
+            tele_vec = (jnp.stack(_round_telemetry(trees, smask, g,
+                                                   cfg.tree.max_depth))
+                        if telemetry else None)
+            tr_vec = jax.lax.cond(
                 xs["do_eval"],
-                lambda m: obj.metric_vector(y_valid.astype(jnp.float32), m),
+                lambda m: obj.metric_vector(y32, m),
                 lambda m: nan_vec,
-                y_hat_valid,
+                y_hat,
             )
+            va_vec = nan_vec
+            if has_valid:
+                vp = tree_mod.predict_trees(trees, binned_valid,
+                                            cfg.tree.max_depth)
+                y_hat_valid = y_hat_valid + lr * jnp.mean(vp, axis=0)
+                va_vec = jax.lax.cond(
+                    xs["do_eval"],
+                    lambda m: obj.metric_vector(y_valid.astype(jnp.float32),
+                                                m),
+                    lambda m: nan_vec,
+                    y_hat_valid,
+                )
         ys = ((trees, tr_vec, va_vec, tele_vec) if telemetry
               else (trees, tr_vec, va_vec))
         return (y_hat, y_hat_valid), ys
@@ -646,38 +675,45 @@ def _scan_train_program(
     # the segment ticks back to rounds.  Under a resume window the plan is
     # the full schedule's plan clipped to [start, stop) — keys/masks index
     # by ABSOLUTE round, so every executed round replays its full-run draw.
-    _emit_tick(0, y_hat0)
+    if ticks:
+        _emit_tick(0, y_hat0)
     for seg_idx, (width, first, n_rounds, rdr) in enumerate(
         _plan_segments(cfg, n, start, stop)
     ):
         s, e = int(offsets[first]), int(offsets[first + n_rounds])
         xs = {"do_eval": jnp.asarray(do_eval[first:first + n_rounds])}
-        if use_goss:
-            xs["keys"] = step_keys[s:e].reshape(n_rounds, width, 2)
-            xs["n_top"] = jnp.asarray(goss_round[first:first + n_rounds, 0])
-            xs["n_rand"] = jnp.asarray(goss_round[first:first + n_rounds, 1])
-        else:
-            xs["smask"] = smask_all[s:e].reshape(n_rounds, width, n)
-            xs["fmask"] = fmask_all[s:e].reshape(n_rounds, width, d)
-        if round_mask is not None:
-            xs["rmask"] = round_mask[first:first + n_rounds]
         body = partial(round_body, rdr)
-        if n_rounds == 1:
-            carry, ys = body(
-                carry, jax.tree_util.tree_map(lambda a: a[0], xs)
-            )
-            ys = jax.tree_util.tree_map(lambda a: a[None], ys)
-        else:
-            carry, ys = jax.lax.scan(body, carry, xs)
+        with jax.named_scope(f"fedgbf.segment.T{width}"):
+            with jax.named_scope("fedgbf.sample"):
+                if use_goss:
+                    xs["keys"] = step_keys[s:e].reshape(n_rounds, width, 2)
+                    xs["n_top"] = jnp.asarray(
+                        goss_round[first:first + n_rounds, 0])
+                    xs["n_rand"] = jnp.asarray(
+                        goss_round[first:first + n_rounds, 1])
+                else:
+                    xs["smask"] = smask_all[s:e].reshape(n_rounds, width, n)
+                    xs["fmask"] = fmask_all[s:e].reshape(n_rounds, width, d)
+                if round_mask is not None:
+                    xs["rmask"] = round_mask[first:first + n_rounds]
+            if n_rounds == 1:
+                carry, ys = body(
+                    carry, jax.tree_util.tree_map(lambda a: a[0], xs)
+                )
+                ys = jax.tree_util.tree_map(lambda a: a[None], ys)
+            else:
+                carry, ys = jax.lax.scan(body, carry, xs)
         trees_segs.append(ys[0])
         tr_rows.append(ys[1])
         va_rows.append(ys[2])
         if telemetry:
             tele_rows.append(ys[3])
-        _emit_tick(seg_idx + 1, carry[0])
-    tr_mat = jnp.concatenate(tr_rows)  # (stop - start, len(keys))
-    va_mat = jnp.concatenate(va_rows) if has_valid else None
-    tele_mat = jnp.concatenate(tele_rows) if telemetry else None
+        if ticks:
+            _emit_tick(seg_idx + 1, carry[0])
+    with jax.named_scope("fedgbf.eval"):
+        tr_mat = jnp.concatenate(tr_rows)  # (stop - start, len(keys))
+        va_mat = jnp.concatenate(va_rows) if has_valid else None
+        tele_mat = jnp.concatenate(tele_rows) if telemetry else None
     return tuple(trees_segs), tr_mat, va_mat, tele_mat, carry
 
 
@@ -708,6 +744,7 @@ def _train_scanned(
     rounds_idx = np.arange(1, cfg.rounds + 1)
     do_eval = (rounds_idx % eval_every == 0) | (rounds_idx == cfg.rounds)
 
+    ticks = tracer.records  # segment ticks only for a tracer that keeps them
     _SEGMENT_TICKS.clear()
     t0 = time.perf_counter()
     with tracer.span("scan_program", cat="train",
@@ -722,10 +759,11 @@ def _train_scanned(
                          else jnp.asarray(init_margin)),
             init_margin_valid=(None if init_margin_valid is None
                                else jnp.asarray(init_margin_valid)),
-            start_round=start, stop_round=stop,
+            start_round=start, stop_round=stop, ticks=ticks,
         )
         jax.block_until_ready(trees_segs)
-    jax.effects_barrier()  # flush the in-program segment ticks
+    if ticks:
+        jax.effects_barrier()  # flush the in-program segment ticks
     wall = time.perf_counter() - t0
     with tracer.span("fetch_history", cat="train"):
         # ONE fetch for the whole metric (+ telemetry) history — the
@@ -736,14 +774,15 @@ def _train_scanned(
 
     # Unstack each segment's (rounds_seg, width, ...) trees into the ragged
     # per-round forests — structurally identical to the legacy loop's model.
-    forests = []
-    for seg_trees in trees_segs:
-        rounds_seg = seg_trees.feature.shape[0]
-        for r in range(rounds_seg):
-            forests.append(
-                jax.tree_util.tree_map(lambda a: a[r], seg_trees)
-            )
-    forests = tuple(forests)
+    with tracer.span("assemble_model", cat="train"):
+        forests = []
+        for seg_trees in trees_segs:
+            rounds_seg = seg_trees.feature.shape[0]
+            for r in range(rounds_seg):
+                forests.append(
+                    jax.tree_util.tree_map(lambda a: a[r], seg_trees)
+                )
+        forests = tuple(forests)
 
     history = TrainHistory(engine="scan", start_round=start)
     history.n_trees = [int(v) for v in sched.n_trees[start:stop]]
@@ -787,8 +826,8 @@ def _train_scanned(
         history.overhead_s = max(0.0, wall - (ticks[-1][1] - ticks[0][1]))
         tracer.add_span("trace+compile+dispatch", t0, ticks[0][1],
                         cat="train", track="train")
-    else:  # ticks unavailable (e.g. a backend without host callbacks):
-        # fall back to the uniform smear so the total stays true.
+    else:  # no ticks (the default program, or a backend without host
+        # callbacks): the uniform smear, so the total stays true.
         n_exec = stop - start
         history.wall_time_s = [wall / n_exec] * n_exec
         per = wall / n_exec

@@ -182,12 +182,14 @@ def _forest_per_tree(binned, g, h, sample_mask, feature_mask, cfg, backend=None,
         binned, g, h, sample_mask, feature_mask, cfg, backend=backend,
         root_delta_rows=root_delta_rows,
     )
-    if trees.leaf_weight.ndim == 3:  # K-channel leaf table: (T, L, K)
-        per_tree_pred = jnp.take_along_axis(
-            trees.leaf_weight, assign[..., None], axis=1
-        )  # (T, n, K)
-    else:
-        per_tree_pred = jnp.take_along_axis(trees.leaf_weight, assign, axis=1)
+    with jax.named_scope("fedgbf.update"):
+        if trees.leaf_weight.ndim == 3:  # K-channel leaf table: (T, L, K)
+            per_tree_pred = jnp.take_along_axis(
+                trees.leaf_weight, assign[..., None], axis=1
+            )  # (T, n, K)
+        else:
+            per_tree_pred = jnp.take_along_axis(trees.leaf_weight, assign,
+                                                axis=1)
     return trees, per_tree_pred
 
 

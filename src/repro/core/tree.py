@@ -236,6 +236,12 @@ def build_round(
     update y_hat on the full training set; masked-out samples simply do not
     contribute to histograms or leaf weights.
 
+    Each level's phases run under ``jax.named_scope`` — ``fedgbf.histogram``
+    (compaction, accumulation, sibling derivation), ``fedgbf.split``,
+    ``fedgbf.route`` — and the leaves under ``fedgbf.leaf``: compile-time
+    op metadata that a profiler capture reports per device op (DESIGN.md
+    §12); the program's arithmetic is unchanged.
+
     Args:
       binned: (n, d) int32 binned features (the *local feature shard* on the
         federated path — d is then d_party, not d_global).
@@ -272,115 +278,129 @@ def build_round(
         width = 2 ** level
         A = cfg.active_width(level)
         compacted = A < width
-        if compacted:
-            # Frontier compaction (§9): gather live nodes into dense slots.
-            # ``order`` is a stable permutation putting live node ids first
-            # (ascending), so slot k < live_count holds the k-th live node;
-            # overflow beyond the budget and dead nodes route through the
-            # full-width level arrays as unsplit (-1) entries.
-            order = jnp.argsort(~live, axis=1)
-            slot_node = order[:, :A].astype(jnp.int32)       # (T, A)
-            live_count = jnp.sum(live, axis=1).astype(jnp.int32)
-            slot_valid = (
-                jnp.arange(A, dtype=jnp.int32)[None, :] < live_count[:, None]
-            )
-            # node -> slot table; dead nodes map to the trash id A (their
-            # samples are weight-masked out of the histogram pass), invalid
-            # slots scatter into a dummy row that is never read.
-            scatter_node = jnp.where(slot_valid, slot_node, width)
-            table = jnp.full((T, width + 1), A, jnp.int32)
-            table = table.at[t_rows, scatter_node].set(
-                jnp.broadcast_to(
-                    jnp.arange(A, dtype=jnp.int32)[None, :], (T, A)
-                )
-            )
-            slot_assign = jnp.take_along_axis(table, assign, axis=1)
-            w_level = sample_mask * (slot_assign < A).astype(sample_mask.dtype)
-            id_level = jnp.minimum(slot_assign, A - 1)
-        else:
-            slot_node = table = slot_valid = None
-            w_level = sample_mask
-            id_level = assign
-
-        if cfg.hist_subtraction and level >= 1:
-            # Subtraction pipeline (§6): accumulate only the left children
-            # at parent-slot width and derive every right sibling from the
-            # carried parent histograms; under compaction the interleaved
-            # child-slot frontier is then gathered into this level's dense
-            # slots (dead children never reach the histogram/exchange).
-            side = (assign % 2).astype(jnp.int32)
-            cslot = prev_id * 2 + side          # child-slot space, 2*prev_A
-            left = child_fn(binned, g, h, prev_w, cslot, prev_A, cfg.num_bins,
-                            level=level)
-            sib = hist_mod.derive_sibling(prev_hist, left)  # (T, 2*prev_A, ...)
+        with jax.named_scope("fedgbf.histogram"):
             if compacted:
-                # A live slot's parent is itself a valid previous-level slot
-                # (liveness requires a split parent); invalid slots gather
-                # clipped junk that the decision scatter discards.  The
-                # budget is monotone in the level width, so a compacted
-                # level's PREVIOUS level may be uncompacted (prev_table is
-                # None, parent slot == parent node) but never vice versa.
-                pslot = (
-                    jnp.take_along_axis(prev_table, slot_node // 2, axis=1)
-                    if prev_table is not None else slot_node // 2
+                # Frontier compaction (§9): gather live nodes into dense
+                # slots.  ``order`` is a stable permutation putting live
+                # node ids first (ascending), so slot k < live_count holds
+                # the k-th live node; overflow beyond the budget and dead
+                # nodes route through the full-width level arrays as
+                # unsplit (-1) entries.
+                order = jnp.argsort(~live, axis=1)
+                slot_node = order[:, :A].astype(jnp.int32)       # (T, A)
+                live_count = jnp.sum(live, axis=1).astype(jnp.int32)
+                slot_valid = (jnp.arange(A, dtype=jnp.int32)[None, :]
+                              < live_count[:, None])
+                # node -> slot table; dead nodes map to the trash id A
+                # (their samples are weight-masked out of the histogram
+                # pass), invalid slots scatter into a dummy row that is
+                # never read.
+                scatter_node = jnp.where(slot_valid, slot_node, width)
+                table = jnp.full((T, width + 1), A, jnp.int32)
+                table = table.at[t_rows, scatter_node].set(
+                    jnp.broadcast_to(
+                        jnp.arange(A, dtype=jnp.int32)[None, :], (T, A)
+                    )
                 )
-                cidx = jnp.clip(pslot * 2 + slot_node % 2, 0, 2 * prev_A - 1)
-                hist = jnp.take_along_axis(
-                    sib, cidx[:, :, None, None, None], axis=1
+                slot_assign = jnp.take_along_axis(table, assign, axis=1)
+                w_level = sample_mask * (slot_assign < A).astype(
+                    sample_mask.dtype)
+                id_level = jnp.minimum(slot_assign, A - 1)
+            else:
+                slot_node = table = slot_valid = None
+                w_level = sample_mask
+                id_level = assign
+
+            if cfg.hist_subtraction and level >= 1:
+                # Subtraction pipeline (§6): accumulate only the left
+                # children at parent-slot width and derive every right
+                # sibling from the carried parent histograms; under
+                # compaction the interleaved child-slot frontier is then
+                # gathered into this level's dense slots (dead children
+                # never reach the histogram/exchange).
+                side = (assign % 2).astype(jnp.int32)
+                cslot = prev_id * 2 + side      # child-slot space, 2*prev_A
+                left = child_fn(binned, g, h, prev_w, cslot, prev_A,
+                                cfg.num_bins, level=level)
+                # (T, 2*prev_A, ...)
+                sib = hist_mod.derive_sibling(prev_hist, left)
+                if compacted:
+                    # A live slot's parent is itself a valid previous-level
+                    # slot (liveness requires a split parent); invalid slots
+                    # gather clipped junk that the decision scatter
+                    # discards.  The budget is monotone in the level width,
+                    # so a compacted level's PREVIOUS level may be
+                    # uncompacted (prev_table is None, parent slot ==
+                    # parent node) but never vice versa.
+                    pslot = (
+                        jnp.take_along_axis(prev_table, slot_node // 2, axis=1)
+                        if prev_table is not None else slot_node // 2
+                    )
+                    cidx = jnp.clip(pslot * 2 + slot_node % 2, 0,
+                                    2 * prev_A - 1)
+                    hist = jnp.take_along_axis(
+                        sib, cidx[:, :, None, None, None], axis=1
+                    )
+                else:
+                    hist = sib
+            else:
+                kw = {"level": level}
+                if level == 0 and root_delta_rows:
+                    # Shared-root caching (§9): the provider derives every
+                    # root as shared − delta inside its own program, so
+                    # federated transports still ship the standard
+                    # per-tree payload.
+                    kw["root_delta_rows"] = root_delta_rows
+                hist = hist_fn(binned, g, h, w_level, id_level, A,
+                               cfg.num_bins, **kw)
+
+        with jax.named_scope("fedgbf.split"):
+            decision = choose_fn(hist, feature_mask)          # (T, A) fields
+            gain_pos = jnp.maximum(decision.gain, 0.0)
+            if compacted:
+                feat = jnp.where(slot_valid, decision.feature, -1)
+                thr = jnp.where(slot_valid, decision.threshold, cfg.num_bins)
+                gn = jnp.where(slot_valid, gain_pos, 0.0)
+                feature_lvl = (
+                    jnp.full((T, width), -1, jnp.int32)
+                    .at[t_rows, slot_node].set(feat)
+                )
+                threshold_lvl = (
+                    jnp.full((T, width), cfg.num_bins, jnp.int32)
+                    .at[t_rows, slot_node].set(thr)
+                )
+                gain_lvl = (
+                    jnp.zeros((T, width), jnp.float32)
+                    .at[t_rows, slot_node].set(gn)
+                )
+                decision_lvl = split_mod.SplitDecision(
+                    feature=feature_lvl, threshold=threshold_lvl, gain=gain_lvl
                 )
             else:
-                hist = sib
-        else:
-            kw = {"level": level}
-            if level == 0 and root_delta_rows:
-                # Shared-root caching (§9): the provider derives every root
-                # as shared − delta inside its own program, so federated
-                # transports still ship the standard per-tree payload.
-                kw["root_delta_rows"] = root_delta_rows
-            hist = hist_fn(binned, g, h, w_level, id_level, A, cfg.num_bins, **kw)
+                feature_lvl, threshold_lvl, gain_lvl = (
+                    decision.feature, decision.threshold, gain_pos
+                )
+                decision_lvl = decision
+            features.append(feature_lvl)
+            thresholds.append(threshold_lvl)
+            gains.append(gain_lvl)
+        with jax.named_scope("fedgbf.route"):
+            assign = route_fn(binned, assign, decision_lvl)
 
-        decision = choose_fn(hist, feature_mask)          # (T, A) fields
-        gain_pos = jnp.maximum(decision.gain, 0.0)
-        if compacted:
-            feat = jnp.where(slot_valid, decision.feature, -1)
-            thr = jnp.where(slot_valid, decision.threshold, cfg.num_bins)
-            gn = jnp.where(slot_valid, gain_pos, 0.0)
-            feature_lvl = (
-                jnp.full((T, width), -1, jnp.int32).at[t_rows, slot_node].set(feat)
-            )
-            threshold_lvl = (
-                jnp.full((T, width), cfg.num_bins, jnp.int32)
-                .at[t_rows, slot_node].set(thr)
-            )
-            gain_lvl = (
-                jnp.zeros((T, width), jnp.float32).at[t_rows, slot_node].set(gn)
-            )
-            decision_lvl = split_mod.SplitDecision(
-                feature=feature_lvl, threshold=threshold_lvl, gain=gain_lvl
-            )
-        else:
-            feature_lvl, threshold_lvl, gain_lvl = (
-                decision.feature, decision.threshold, gain_pos
-            )
-            decision_lvl = decision
-        features.append(feature_lvl)
-        thresholds.append(threshold_lvl)
-        gains.append(gain_lvl)
-        assign = route_fn(binned, assign, decision_lvl)
-
-        next_level = level + 1
-        if (next_level < cfg.max_depth
-                and cfg.active_width(next_level) < 2 ** next_level):
-            # Liveness for the next (compacted) level: a child is live iff
-            # its parent split AND it holds weighted samples.  Counts go
-            # through the leaf provider so sample-sharded backends psum to
-            # the global count (a cheap (n,) pass, no party collective —
-            # weights and routing are party-replicated).
-            # count is the LAST stat channel at any K (index 2 when K = 1)
-            counts = leaf_fn(g, h, sample_mask, assign, 2 ** next_level)[..., -1]
-            live = (counts > 0) & jnp.repeat(feature_lvl >= 0, 2, axis=1)
-        else:
-            live = None
+            next_level = level + 1
+            if (next_level < cfg.max_depth
+                    and cfg.active_width(next_level) < 2 ** next_level):
+                # Liveness for the next (compacted) level: a child is live iff
+                # its parent split AND it holds weighted samples.  Counts go
+                # through the leaf provider so sample-sharded backends psum to
+                # the global count (a cheap (n,) pass, no party collective —
+                # weights and routing are party-replicated).
+                # count is the LAST stat channel at any K (index 2 when K = 1)
+                counts = leaf_fn(g, h, sample_mask, assign,
+                                 2 ** next_level)[..., -1]
+                live = (counts > 0) & jnp.repeat(feature_lvl >= 0, 2, axis=1)
+            else:
+                live = None
         prev_hist, prev_id, prev_w = hist, id_level, w_level
         prev_A, prev_table = A, table
 
@@ -389,15 +409,17 @@ def build_round(
     # in plaintext, so leaf weights are computed locally (Alg. 2 step 14);
     # the leaf provider is only overridden when samples are sharded over the
     # data axis (psum of the additive stats, no party gather).
-    leaf_hist = leaf_fn(g, h, sample_mask, assign, cfg.num_leaves)  # (T, L, 2K+1)
-    weights = split_mod.leaf_weights(leaf_hist, cfg)           # (T, L[, K])
+    with jax.named_scope("fedgbf.leaf"):
+        # (T, L, 2K+1) statistics, (T, L[, K]) weights
+        leaf_hist = leaf_fn(g, h, sample_mask, assign, cfg.num_leaves)
+        weights = split_mod.leaf_weights(leaf_hist, cfg)
 
-    trees = TreeArrays(
-        feature=jnp.concatenate(features, axis=1),
-        threshold=jnp.concatenate(thresholds, axis=1),
-        gain=jnp.concatenate(gains, axis=1),
-        leaf_weight=weights,
-    )
+        trees = TreeArrays(
+            feature=jnp.concatenate(features, axis=1),
+            threshold=jnp.concatenate(thresholds, axis=1),
+            gain=jnp.concatenate(gains, axis=1),
+            leaf_weight=weights,
+        )
     return trees, assign
 
 

@@ -81,11 +81,12 @@ def federated_round_histogram_fn(
         local = base_fn(binned_shard, g, h, weight, assign, num_nodes,
                         num_bins, root_delta_rows=root_delta_rows,
                         level=level)
-        for ax in data_axes:
-            local = jax.lax.psum(local, ax)
-        if meter is not None:
-            meter.record("histograms", local)
-        return gather(local, party_axis, 2)
+        with jax.named_scope("fedgbf.exchange"):
+            for ax in data_axes:
+                local = jax.lax.psum(local, ax)
+            if meter is not None:
+                meter.record("histograms", local)
+            return gather(local, party_axis, 2)
 
     return fn
 
@@ -103,8 +104,9 @@ def local_round_histogram_fn(
         local = base_fn(binned_shard, g, h, weight, assign, num_nodes,
                         num_bins, root_delta_rows=root_delta_rows,
                         level=level)
-        for ax in data_axes:
-            local = jax.lax.psum(local, ax)
+        with jax.named_scope("fedgbf.exchange"):
+            for ax in data_axes:
+                local = jax.lax.psum(local, ax)
         return local
 
     return fn
@@ -118,8 +120,9 @@ def local_round_leaf_fn(data_axes: tuple = ()):
 
     def fn(g, h, weight, assign, num_leaves):
         local = hist_mod.round_leaf_stats(g, h, weight, assign, num_leaves)
-        for ax in data_axes:
-            local = jax.lax.psum(local, ax)
+        with jax.named_scope("fedgbf.exchange"):
+            for ax in data_axes:
+                local = jax.lax.psum(local, ax)
         return local
 
     return fn
@@ -137,9 +140,10 @@ def centralized_round_choose_fn(
     def fn(hist_global, feature_mask_local):
         if meter is not None:
             meter.record("feature_mask", feature_mask_local)
-        fmask = jax.lax.all_gather(
-            feature_mask_local, party_axis, axis=1, tiled=True
-        )
+        with jax.named_scope("fedgbf.exchange"):
+            fmask = jax.lax.all_gather(
+                feature_mask_local, party_axis, axis=1, tiled=True
+            )
         return split_mod.choose_splits_round(hist_global, fmask, cfg)
 
     return fn
@@ -198,7 +202,8 @@ def federated_round_route_fn(party_axis: str = mesh_roles.PARTY_AXIS,
         packed_local = pack_bits(go_right_local)  # (T, ceil(n/8)) uint8
         if meter is not None:
             meter.record("id_partition", packed_local)
-        packed = jax.lax.psum(packed_local, party_axis)  # carry-free == OR
+        with jax.named_scope("fedgbf.exchange"):
+            packed = jax.lax.psum(packed_local, party_axis)  # carry-free == OR
         return assign * 2 + unpack_bits(packed, n)
 
     return fn

@@ -393,8 +393,9 @@ def quantized_round_histogram_fn(
         local = base_fn(binned_shard, g, h, weight, assign, num_nodes,
                         num_bins, root_delta_rows=root_delta_rows,
                         level=level)
-        for ax in data_axes:
-            local = jax.lax.psum(local, ax)
+        with jax.named_scope("fedgbf.exchange"):
+            for ax in data_axes:
+                local = jax.lax.psum(local, ax)
         # everything but the trailing count channel traverses the wire:
         # (T, nodes, d_party, B, 2K) — GH_STATS (= 2) at K = 1.
         payload = local[..., :-1]
@@ -409,8 +410,9 @@ def quantized_round_histogram_fn(
         if meter is not None:
             meter.record("histograms", q)
             meter.record("histograms", scale)
-        q_g = gather(q, party_axis, 2)
-        s_g = jax.lax.all_gather(scale, party_axis, axis=2, tiled=True)
+        with jax.named_scope("fedgbf.exchange"):
+            q_g = gather(q, party_axis, 2)
+            s_g = jax.lax.all_gather(scale, party_axis, axis=2, tiled=True)
         deq = dequantize_stats(q_g, s_g)  # (T, nodes, d, B, 2)
         count = jnp.zeros(deq.shape[:-1] + (1,), deq.dtype)
         return jnp.concatenate([deq, count], axis=-1)
@@ -474,9 +476,10 @@ def topk_choose_fn(
         if meter is not None:
             for arr in (top_gain, feat, thr):
                 meter.record("split_candidates", arr)
-        gains_all = gather(top_gain, party_axis)  # (P, nodes, k)
-        feats_all = gather(feat, party_axis)
-        thrs_all = gather(thr, party_axis)
+        with jax.named_scope("fedgbf.exchange"):
+            gains_all = gather(top_gain, party_axis)  # (P, nodes, k)
+            feats_all = gather(feat, party_axis)
+            thrs_all = gather(thr, party_axis)
         num_parties = gains_all.shape[0]
         merge = lambda a: jnp.moveaxis(a, 1, 0).reshape(
             num_nodes, num_parties * k_eff

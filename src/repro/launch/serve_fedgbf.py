@@ -62,7 +62,9 @@ from repro.core import objective as objective_mod
 from repro.core.types import PackedEnsemble
 from repro.data import synthetic
 from repro.launch import compile_cache
+from repro.obs import compiles as obs_compiles
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 
 
 @partial(jax.jit, static_argnames=("impl",))
@@ -98,6 +100,10 @@ class StreamMetrics:
     hot-swap) resets the accumulators and bumps
     ``fedgbf_serve_model_generation``, so a swap never blends two models'
     padding behavior into one gauge.
+
+    The process's compile counters (``obs.compiles``) render with the
+    bundle, so a scrape shows any compile during live serving, which the
+    warmed ladder should never see.
     """
 
     def __init__(self, batch_size: int) -> None:
@@ -144,6 +150,9 @@ class StreamMetrics:
             "fedgbf_serve_model_generation",
             "Model segment counter: bumped on every successful hot-swap; "
             "per-segment gauges reset at each bump.")
+        self.compiles = obs_compiles.install()
+        for m in self.compiles.instruments:
+            r.adopt(m)
         self.batch_size.set(batch_size)
         self._capacity = batch_size
         self._rung_hists: dict = {}
@@ -336,7 +345,15 @@ def serve_stream(
     ``fedgbf_serve_rows_rejected_total``) or zero-padding to the admitted
     capacity.  Plain NaN features are NOT rejected: the fused traversal
     routes them left, the same reserved-NAN_BIN semantics training used.
+
+    Each microbatch opens five spans on the process-global tracer
+    (DESIGN.md §12), in order: ``serve.admit`` (the ladder's pick),
+    ``serve.stage`` (inf scan, padding copy), ``serve.dispatch`` (host to
+    device copy and the program call), ``serve.device`` (waiting for the
+    scores) and ``serve.fetch`` (device slice, device to host copy, NaN
+    marks, write-back).  Under ``NULL_TRACER`` they cost a method call.
     """
+    tracer = obs_trace.global_tracer()
     n = x.shape[0]
     out = None  # allocated after the first batch: (n,) or (n, K) scores
     if metrics is None:
@@ -347,31 +364,35 @@ def serve_stream(
         if swap_plan and batch_idx in swap_plan:
             slot.try_reload(swap_plan[batch_idx])
         queued = n - pos
-        cap = ladder.pick(queued, p99_budget_s, metrics)
+        with tracer.span("serve.admit"):
+            cap = ladder.pick(queued, p99_budget_s, metrics)
         real = min(cap, queued)
-        view = x[pos:pos + real]
-        bad = np.isinf(view).any(axis=1)
-        nbad = int(bad.sum())
-        if nbad or real < cap:
-            batch = np.zeros((cap,) + x.shape[1:], x.dtype)
-            batch[:real] = view
-            if nbad:
-                batch[:real][bad] = 0.0
-            metrics.rows_rejected.inc(nbad)
-        else:
-            batch = view
+        with tracer.span("serve.stage"):
+            view = x[pos:pos + real]
+            bad = np.isinf(view).any(axis=1)
+            nbad = int(bad.sum())
+            if nbad or real < cap:
+                batch = np.zeros((cap,) + x.shape[1:], x.dtype)
+                batch[:real] = view
+                if nbad:
+                    batch[:real][bad] = 0.0
+                metrics.rows_rejected.inc(nbad)
+            else:
+                batch = view
         t0 = time.perf_counter()
-        scores = jax.block_until_ready(
-            _score_batch(slot.packed, jnp.asarray(batch), slot.impl)
-        )
+        with tracer.span("serve.dispatch"):
+            scores = _score_batch(slot.packed, jnp.asarray(batch), slot.impl)
+        with tracer.span("serve.device"):
+            scores = jax.block_until_ready(scores)
         metrics.observe_batch(time.perf_counter() - t0, real, capacity=cap)
-        if out is None:
-            out = np.empty((n,) + scores.shape[1:], np.float32)
-        block = np.asarray(scores[:real])
-        if nbad:
-            block = block.copy()
-            block[bad] = np.nan
-        out[pos:pos + real] = block
+        with tracer.span("serve.fetch"):
+            if out is None:
+                out = np.empty((n,) + scores.shape[1:], np.float32)
+            block = np.asarray(scores[:real])
+            if nbad:
+                block = block.copy()
+                block[bad] = np.nan
+            out[pos:pos + real] = block
         pos += real
         batch_idx += 1
     return out, metrics

@@ -251,7 +251,9 @@ def main() -> None:
     args = ap.parse_args()
 
     want_obs = bool(args.trace) or args.log_json
-    tracer = obs_trace.Tracer() if args.trace else obs_trace.NULL_TRACER
+    # a recording tracer compiles the scan engine's segment ticks in, so
+    # both outputs carry true per-segment walls (DESIGN.md §12)
+    tracer = obs_trace.Tracer() if want_obs else obs_trace.NULL_TRACER
     obs_trace.set_global_tracer(tracer)  # checkpoint I/O etc. hang off this
 
     ds = synthetic.load(args.dataset, n=args.n or None)

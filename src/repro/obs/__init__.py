@@ -4,8 +4,12 @@ metrics (DESIGN.md §12).
 Split by concern so nothing here drags jax into import time:
 
 * ``trace``    — ``Span``/``Tracer`` with a zero-overhead disabled path
-                 (``NULL_TRACER``) plus the process-global tracer seam the
-                 launchers flip on with ``--trace``;
+                 (``NULL_TRACER``), the profiler-only ``AnnotatingTracer``,
+                 plus the process-global tracer seam the launchers flip on
+                 with ``--trace``; live spans land in ``jax.profiler``
+                 captures as ``fedgbf.<name>`` annotations;
+* ``compiles`` — JAX's trace/compile/persistent-cache events as counters
+                 (``install()``), rendered with the serving metrics;
 * ``perfetto`` — Chrome-trace/Perfetto JSON exporter merging host spans,
                  per-round ``TrainHistory`` timing/telemetry, and the
                  ledger's per-round wire bytes into one timeline;
@@ -17,6 +21,7 @@ Split by concern so nothing here drags jax into import time:
 
 from repro.obs.trace import (  # noqa: F401
     NULL_TRACER,
+    AnnotatingTracer,
     Span,
     Tracer,
     global_tracer,

@@ -176,6 +176,11 @@ class MetricsRegistry:
     def histogram(self, name: str, help: str = "", **kw) -> LogBucketHistogram:
         return self._register(LogBucketHistogram(name, help, **kw))
 
+    def adopt(self, metric):
+        """Render an instrument another registry owns: shared, not copied,
+        so it keeps counting where it was made."""
+        return self._register(metric)
+
     def render(self) -> str:
         """Prometheus text exposition (version 0.0.4)."""
         out, seen = [], set()

@@ -16,19 +16,48 @@ samples.  Spans come in two flavours:
 model): the exporter assigns one tid per track, so host spans, round spans
 and per-phase wire spans land on separate swim-lanes in Perfetto.
 
-The disabled path is ``NULL_TRACER``: ``span()`` returns one shared no-op
-context-manager singleton (no per-call allocation — asserted by
-tests/test_obs.py), ``add_span``/``counter`` are no-ops, so instrumented
-code pays a method call and nothing else when tracing is off.
+Live spans also reach a ``jax.profiler`` capture: each one is entered as a
+``jax.profiler.TraceAnnotation`` named ``fedgbf.<name>``, so it lands on
+the profiler's clock beside the device planes and a device idle gap can be
+charged to the innermost program span around it.  Derived spans cannot:
+the profiler takes no intervals after the fact.
+
+Three tracers share the span API:
+
+* ``NULL_TRACER`` — the disabled path: ``span()`` returns one shared no-op
+  context-manager singleton (no per-call allocation — asserted by
+  tests/test_obs.py), ``add_span``/``counter`` are no-ops, so instrumented
+  code pays a method call and nothing else when tracing is off;
+* ``AnnotatingTracer`` — profiled runs: live spans become profiler
+  annotations and nothing is kept in memory;
+* ``Tracer`` — records every span and counter for the Perfetto export
+  (``train_fedgbf --trace``) and annotates as well.
+
+``records`` is True only for ``Tracer``: the scan engine compiles its
+in-program segment ticks (host callbacks) only for a tracer that records
+them, so the default training program holds no host callback.
 
 ``set_global_tracer`` / ``global_tracer`` is the process-wide seam for code
-that cannot thread a tracer argument (checkpoint I/O, library internals):
-default ``NULL_TRACER``, flipped by ``train_fedgbf --trace`` and friends.
+that cannot thread a tracer argument (checkpoint I/O, ``serve_stream``,
+library internals): default ``NULL_TRACER``, flipped by ``train_fedgbf
+--trace`` and friends, or to an ``AnnotatingTracer`` by a profiled run.
 """
 
 from __future__ import annotations
 
 import time
+
+#: prefix of every program span in a profiler capture
+ANNOTATION_PREFIX = "fedgbf."
+
+
+def annotation(name: str):
+    """The profiler annotation of program span ``name`` (not yet entered).
+    jax is imported here, never at module import: callers that open spans
+    have loaded it already."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(ANNOTATION_PREFIX + name)
 
 
 class Span:
@@ -56,9 +85,11 @@ class Span:
 
 
 class _ActiveSpan:
-    """Live span context manager: times the block, appends on exit."""
+    """Live span context manager: times the block, appends on exit, and
+    annotates the block for the profiler."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_depth")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_depth",
+                 "_note")
 
     def __init__(self, tracer, name, cat, args):
         self._tracer = tracer
@@ -69,11 +100,14 @@ class _ActiveSpan:
     def __enter__(self):
         self._depth = self._tracer._depth
         self._tracer._depth = self._depth + 1
+        self._note = annotation(self._name)
+        self._note.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        self._note.__exit__(exc_type, exc, tb)
         self._tracer._depth = self._depth
         self._tracer.spans.append(
             Span(self._name, self._cat, self._t0, t1, "host", self._args,
@@ -102,6 +136,7 @@ class NullTracer:
     (returns the module-level ``_NULL_SPAN`` singleton)."""
 
     enabled = False
+    records = False
 
     def span(self, name, cat="host", args=None):
         return _NULL_SPAN
@@ -116,11 +151,31 @@ class NullTracer:
 NULL_TRACER = NullTracer()
 
 
-class Tracer:
-    """Recording tracer: ``spans`` (list of ``Span``) and ``counters``
-    (list of ``(name, ts, values_dict)`` samples)."""
+class AnnotatingTracer:
+    """Profiler-only tracer: a live span is a ``fedgbf.<name>`` profiler
+    annotation and nothing else — no memory kept, no in-program ticks.  A
+    profiled run installs it with ``set_global_tracer``."""
 
     enabled = True
+    records = False
+
+    def span(self, name, cat="host", args=None):
+        return annotation(name)
+
+    def add_span(self, name, t0, t1, cat="host", track="host", args=None):
+        pass
+
+    def counter(self, name, values, ts=None):
+        pass
+
+
+class Tracer:
+    """Recording tracer: ``spans`` (list of ``Span``) and ``counters``
+    (list of ``(name, ts, values_dict)`` samples); live spans annotate the
+    profiler too."""
+
+    enabled = True
+    records = True
 
     def __init__(self) -> None:
         self.spans: list = []

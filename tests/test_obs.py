@@ -327,3 +327,67 @@ def test_per_round_cost_sums_to_assembled_run():
     rows2 = led.per_round_measured()
     for phase in protocol.WIRE_PHASES:
         assert sum(r[phase] for r in rows2) == led.measured[phase]
+
+
+# ---------------------------------------------------------------------------
+# Profiler-facing tracing: the annotating tracer and the compile counter
+# ---------------------------------------------------------------------------
+def test_annotating_tracer_keeps_nothing_and_records_no_ticks():
+    tr = trace.AnnotatingTracer()
+    assert tr.enabled and not tr.records
+    assert not trace.NULL_TRACER.records and trace.Tracer().records
+    with tr.span("outer"):
+        with tr.span("inner"):
+            pass
+    tr.add_span("derived", 0.0, 1.0)
+    tr.counter("c", {"v": 1})
+    assert not hasattr(tr, "spans") and not hasattr(tr, "counters")
+    # a span is a fresh profiler annotation named into the fedgbf namespace
+    assert isinstance(tr.span("x"), jax.profiler.TraceAnnotation)
+    assert tr.span("x") is not tr.span("x")
+
+
+def test_recording_tracer_spans_reach_a_profiler_capture(tmp_path):
+    import os
+
+    tr = trace.Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("outer"):
+            with tr.span("inner"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    assert [s.name for s in tr.spans] == ["inner", "outer"]
+    (path,) = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+               for f in fs if f.endswith(".xplane.pb")]
+    data = jax.profiler.ProfileData.from_file(path)
+    names = {e.name for plane in data.planes for line in plane.lines
+             for e in line.events}
+    assert {"fedgbf.outer", "fedgbf.inner"} <= names
+
+
+def test_compile_counter_counts_a_fresh_jit_once_and_a_repeat_never():
+    from repro.launch import serve_fedgbf
+    from repro.obs import compiles
+
+    counter = compiles.install()
+    assert compiles.install() is counter and compiles.installed() is counter
+    f = jax.jit(lambda a: a * 3.0 + 1.0)  # a new function: a fresh compile
+    a = jnp.arange(5.0)
+    before = counter.snapshot()
+    f(a).block_until_ready()
+    first = counter.snapshot()
+    f(a).block_until_ready()
+    again = counter.snapshot()
+    assert first["fedgbf_compiles_total"] - before["fedgbf_compiles_total"] == 1
+    assert first["fedgbf_compile_seconds_total"] > \
+        before["fedgbf_compile_seconds_total"]
+    assert first["fedgbf_trace_seconds_total"] > \
+        before["fedgbf_trace_seconds_total"]
+    assert again == first  # the repeat call builds nothing
+    # the serving bundle renders the process's counters with its own
+    text = serve_fedgbf.StreamMetrics(8).render()
+    assert "# TYPE fedgbf_compiles_total counter" in text
+    assert f"fedgbf_compiles_total {int(again['fedgbf_compiles_total'])}" \
+        in text

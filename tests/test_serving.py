@@ -307,3 +307,42 @@ def test_metrics_http_endpoint_serves_live_registry():
             assert "reqs_total 4" in resp.read().decode()
     finally:
         server.close()
+
+
+# ---------------------------------------------------------------------------
+# Observability (DESIGN.md §12): serve_stream's spans on the profiler clock
+# ---------------------------------------------------------------------------
+SERVE_SPANS = ["fedgbf.serve.admit", "fedgbf.serve.stage",
+               "fedgbf.serve.dispatch", "fedgbf.serve.device",
+               "fedgbf.serve.fetch"]
+
+
+def test_serve_stream_spans_land_in_a_profiler_capture(model_a, tmp_path):
+    from repro.obs import trace
+
+    pe, ds = model_a
+    ladder = serve_fedgbf.BatchLadder([256, 512])
+    slot = serve_fedgbf.ModelSlot(pe, "fused")
+    ladder.warm(pe, pe.bin_edges.shape[0], "fused")
+    x = np.array(ds.x_test[:700], np.float32)  # microbatches of 512, 256
+    trace.set_global_tracer(trace.AnnotatingTracer())
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            out, _ = serve_fedgbf.serve_stream(slot, x, ladder=ladder)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        trace.set_global_tracer(None)
+    assert out.shape == (700,) and np.isfinite(out).all()
+
+    (path,) = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+               for f in fs if f.endswith(".xplane.pb")]
+    data = jax.profiler.ProfileData.from_file(path)
+    events = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                    for plane in data.planes if plane.name.startswith("/host:")
+                    for line in plane.lines for e in line.events
+                    if e.name.startswith("fedgbf.serve."))
+    assert [n for _, _, n in events] == SERVE_SPANS * 2
+    for (_, end, _), (start, _, _) in zip(events, events[1:]):
+        assert end <= start  # one after another, none nested

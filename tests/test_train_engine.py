@@ -227,3 +227,100 @@ def test_training_with_missing_values(engine):
     x_test[rng.random((97, d)) < 0.15] = np.nan
     margin = boosting.predict(model, jnp.asarray(x_test))
     assert np.all(np.isfinite(np.asarray(margin)))
+
+
+# ---------------------------------------------------------------------------
+# Observability (DESIGN.md §12): no host callback unless a tracer records
+# ---------------------------------------------------------------------------
+def test_default_scan_program_holds_no_host_callback():
+    from repro.core import backend as backend_mod
+
+    x, y, _, _ = _data("logistic", n=256, d=5)
+    cfg = boosting.dynamic_fedgbf_config(rounds=4)
+    binned, _ = binning.fit_bin(x, cfg.tree.num_bins)
+    bk = backend_mod.resolve_backend(None)
+
+    def lowered(**kw):
+        return boosting._scan_train_program.lower(
+            binned, y, None, None, jax.random.PRNGKey(0), cfg, bk, 1, **kw
+        ).as_text()
+
+    assert "callback" not in lowered()
+    assert "callback" in lowered(ticks=True)  # the recording-tracer program
+
+
+def test_ticks_change_no_bit_of_the_model():
+    from repro.obs import trace
+
+    x, y, _, _ = _data("logistic", n=256, d=5)
+    cfg = boosting.dynamic_fedgbf_config(rounds=4)
+    m0, h0 = boosting.train_fedgbf(x, y, cfg, jax.random.PRNGKey(3))
+    m1, h1 = boosting.train_fedgbf(x, y, cfg, jax.random.PRNGKey(3),
+                                   tracer=trace.Tracer())
+    for f0, f1 in zip(m0.forests, m1.forests):
+        for a, b in zip(jax.tree_util.tree_leaves(f0),
+                        jax.tree_util.tree_leaves(f1)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(h0.final_margin, h1.final_margin)
+    # without ticks the call's wall is smeared uniformly, as documented
+    assert h0.overhead_s == 0.0
+    assert len(set(h0.wall_time_s)) == 1 and len(h0.wall_time_s) == 4
+    assert [s["rounds"] for s in h0.segments] == \
+        [s["rounds"] for s in h1.segments]
+
+
+SCOPES_PROBE = r"""
+import json, re
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core import backend as backend_mod, binning, boosting
+from repro.launch.mesh import make_vfl_mesh
+
+def scopes(bk, x, y, cfg):
+    binned, _ = binning.fit_bin(x, cfg.tree.num_bins)
+    hlo = boosting._scan_train_program.lower(
+        binned, y, None, None, jax.random.PRNGKey(0), cfg, bk, 1
+    ).compile().as_text()
+    return sorted({p for op in re.findall(r'op_name="([^"]*)"', hlo)
+                   for p in op.split("/") if p.startswith("fedgbf.")})
+
+rng = np.random.default_rng(0)
+x = rng.normal(size=(256, 4)).astype(np.float32)
+y = (x[:, 0] > 0).astype(np.float32)
+cfg = boosting.dynamic_fedgbf_config(rounds=3)
+out = {"local": scopes(backend_mod.get_backend("local"), jnp.asarray(x),
+                       jnp.asarray(y), cfg)}
+mesh = make_vfl_mesh(2, 2)
+bk = backend_mod.get_backend("vfl-histogram-sharded", mesh=mesh, tree=cfg.tree)
+out["vfl-histogram-sharded"] = scopes(
+    bk, jax.device_put(x, NamedSharding(mesh, P("data", "model"))),
+    jax.device_put(y, NamedSharding(mesh, P("data"))), cfg)
+print(json.dumps(out))
+"""
+
+PHASES = {"fedgbf.sample", "fedgbf.grad", "fedgbf.histogram", "fedgbf.split",
+          "fedgbf.route", "fedgbf.leaf", "fedgbf.update", "fedgbf.eval"}
+
+
+def test_compiled_program_names_every_phase_scope():
+    """The op metadata of the compiled training program carries every phase
+    scope, for one device and for 2 parties x 2 row shards on 4 virtual CPU
+    devices; only the sharded backend exchanges."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = os.path.join(repo, "src")
+    proc = subprocess.run([sys.executable, "-c", SCOPES_PROBE], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    for backend, scopes in got.items():
+        assert PHASES <= set(scopes), (backend, scopes)
+        assert any(s.startswith("fedgbf.segment.T") for s in scopes)
+    assert "fedgbf.exchange" in got["vfl-histogram-sharded"]
+    assert "fedgbf.exchange" not in got["local"]

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from repro.core import histogram as hist_mod
 from repro.core import split as split_mod
+from repro.core import tree as tree_mod
 from repro.core.types import TreeConfig
 from repro.federation import mesh_roles
 
@@ -189,16 +190,14 @@ def federated_round_route_fn(party_axis: str = mesh_roles.PARTY_AXIS,
     def fn(binned_shard, assign, decision):
         n, d_party = binned_shard.shape
         p = jax.lax.axis_index(party_axis)
-        f_global = jnp.take_along_axis(decision.feature, assign, axis=1)
-        thr = jnp.take_along_axis(decision.threshold, assign, axis=1)
-        f_local = f_global - p * d_party
+        # Nodes split on another party's feature, and unsplit nodes, take
+        # feature -1 here: they route left and contribute the 0 bit.
+        f_local = decision.feature - p * d_party
         owned = (f_local >= 0) & (f_local < d_party)
-        fv = binned_shard[
-            jnp.arange(n)[None, :], jnp.clip(f_local, 0, d_party - 1)
-        ]  # (T, n)
-        go_right_local = jnp.where(
-            owned & (f_global >= 0), (fv > thr).astype(jnp.int32), 0
-        )
+        go_right_local = tree_mod.level_go_right(
+            binned_shard, assign, jnp.where(owned, f_local, -1),
+            decision.threshold,
+        )  # (T, n)
         packed_local = pack_bits(go_right_local)  # (T, ceil(n/8)) uint8
         if meter is not None:
             meter.record("id_partition", packed_local)

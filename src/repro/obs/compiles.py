@@ -14,7 +14,12 @@ that keeps them in ``MetricsRegistry`` counters:
   ``fedgbf_compile_cache_load_seconds_total`` — the persistent cache
   (``/jax/compilation_cache/cache_hits``, ``cache_misses``,
   ``cache_retrieval_time_sec``; the load seconds are a part of the compile
-  seconds).
+  seconds);
+* ``fedgbf_route_levels_total{form="rows"}`` — tree levels traced into a
+  round-routing program by ``core.tree.level_go_right``'s row compares,
+  while the counter is installed.  It counts at trace time, so a program
+  loaded from the persistent cache adds nothing, and costs the device
+  nothing.
 
 A repeat call of a compiled program fires none of these, so a counter that
 moves during live serving is a compile the batch ladder claims never
@@ -68,6 +73,10 @@ class CompileCounter:
         self.cache_load_seconds = r.counter(
             "fedgbf_compile_cache_load_seconds_total",
             "Seconds spent loading programs from the persistent cache.")
+        self.route_levels = r.counter(
+            "fedgbf_route_levels_total",
+            "Tree levels traced into a round-routing program, routed by "
+            "whole-row compares.", labels={"form": "rows"})
         self._durations = {TRACE_EVENT: self.trace_seconds,
                            CACHE_LOAD_EVENT: self.cache_load_seconds}
         self._events = {CACHE_HIT_EVENT: self.cache_hits,
@@ -76,7 +85,8 @@ class CompileCounter:
     @property
     def instruments(self) -> tuple:
         return (self.compiles, self.compile_seconds, self.trace_seconds,
-                self.cache_hits, self.cache_misses, self.cache_load_seconds)
+                self.cache_hits, self.cache_misses, self.cache_load_seconds,
+                self.route_levels)
 
     def snapshot(self) -> dict:
         """Every counter's value, by metric name."""

@@ -158,3 +158,69 @@ def test_sample_masks_exact_counts():
     assert smask.shape == (8, 1000) and fmask.shape == (8, 10)
     np.testing.assert_array_equal(np.asarray(smask.sum(1)), np.full(8, 300.0))
     np.testing.assert_array_equal(np.asarray(fmask.sum(1)), np.full(8, 5))
+
+
+# ----------------------------------------------------------------------------
+# Round routing: the row-compare form against the per-row gather form.
+# ----------------------------------------------------------------------------
+def per_row_gather_route(binned, idx, feature, threshold):
+    """The element-wise oracle: each (tree, row) gathers its node's feature,
+    threshold and bin."""
+    f = jnp.take_along_axis(feature, idx, axis=1)
+    t = jnp.take_along_axis(threshold, idx, axis=1)
+    rows = jnp.arange(binned.shape[0])
+    fv = binned[rows[None, :], jnp.clip(f, 0, None)]
+    return idx * 2 + ((f >= 0) & (fv > t)).astype(jnp.int32)
+
+
+def _route_case(T, width, d, B=32, n=997, seed=0):
+    """Random level: about a third of the nodes unsplit (-1 / B), and the
+    split nodes' thresholds include both ends, 0 and B - 1."""
+    rng = np.random.default_rng(seed)
+    binned = rng.integers(0, B, (n, d))
+    binned[: n // 8] = B - 1       # some rows at the top bin
+    binned[n // 8: n // 4] = 0     # and some at the bottom
+    feature = rng.integers(0, d, (T, width))
+    threshold = rng.integers(0, B, (T, width))
+    threshold.flat[::2] = rng.choice([0, B - 1], threshold.flat[::2].shape)
+    unsplit = rng.random((T, width)) < 1 / 3
+    feature[unsplit], threshold[unsplit] = -1, B
+    idx = rng.integers(0, width, (T, n))
+    decision = split.SplitDecision(
+        feature=jnp.asarray(feature, jnp.int32),
+        threshold=jnp.asarray(threshold, jnp.int32),
+        gain=jnp.zeros((T, width), jnp.float32),
+    )
+    return jnp.asarray(binned, jnp.int32), jnp.asarray(idx, jnp.int32), decision
+
+
+@pytest.mark.parametrize("d", [5, 10, 23])
+@pytest.mark.parametrize("width", [1, 2, 4, 64])
+@pytest.mark.parametrize("T", [1, 2, 5])
+def test_row_compare_routing_equals_per_row_gather(T, width, d):
+    binned, idx, dec = _route_case(T, width, d, seed=T * 1000 + width * 10 + d)
+    got = jax.jit(tree.route_local_round)(binned, idx, dec)
+    want = per_row_gather_route(binned, idx, dec.feature, dec.threshold)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("width", [1, 4])
+def test_route_level_is_counted_as_rows(width):
+    """Each level traced into a round route counts once in
+    ``fedgbf_route_levels_total{form="rows"}``; a repeat call of the
+    compiled program counts nothing."""
+    from repro.obs import compiles
+
+    binned, idx, dec = _route_case(3, width, 7, seed=width)
+    counter = compiles.install().route_levels
+    before = counter.value
+    route = jax.jit(tree.route_local_round)
+    got = route(binned, idx, dec)
+    route(binned, idx, dec)
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        np.asarray(per_row_gather_route(binned, idx, dec.feature,
+                                        dec.threshold)),
+    )
+    assert counter.value - before == 1

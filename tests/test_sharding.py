@@ -17,8 +17,16 @@ property over the same space when the package is installed.
 
 The id_partition bit-packing (federation/aggregator.py) rides along: the
 pack/unpack round-trip, the carry-free psum-equals-OR property under
-disjoint party ownership, and the shard-aware wire-model arithmetic.
+disjoint party ownership, and the shard-aware wire-model arithmetic.  So
+does the federated round route: on a CPU party mesh (a subprocess with
+forced host devices) it matches the per-row gather formulation bit for bit
+and meters the same id_partition bytes.
 """
+
+import json
+import os
+import subprocess
+import sys
 
 import jax.numpy as jnp
 import numpy as np
@@ -180,6 +188,91 @@ def _check_wire_arithmetic(n, shards, depth):
     assert phases["id_partition"] - unsharded["id_partition"] <= depth * shards
 
 
+ROUTE_PROBE = r"""
+import json, sys
+import jax, jax.numpy as jnp, numpy as np
+from jax import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core import split
+from repro.federation import aggregator, compress
+from repro.launch.mesh import make_vfl_mesh
+
+def per_row_gather_route_fn(meter):
+    # the element-wise formulation: each (tree, row) gathers its node's
+    # global feature and threshold, then its bin on the owning party
+    def fn(binned_shard, assign, decision):
+        n, d_party = binned_shard.shape
+        p = jax.lax.axis_index("model")
+        f_global = jnp.take_along_axis(decision.feature, assign, axis=1)
+        thr = jnp.take_along_axis(decision.threshold, assign, axis=1)
+        f_local = f_global - p * d_party
+        owned = (f_local >= 0) & (f_local < d_party)
+        fv = binned_shard[
+            jnp.arange(n)[None, :], jnp.clip(f_local, 0, d_party - 1)]
+        bits = jnp.where(owned & (f_global >= 0),
+                         (fv > thr).astype(jnp.int32), 0)
+        packed_local = aggregator.pack_bits(bits)
+        meter.record("id_partition", packed_local)
+        packed = jax.lax.psum(packed_local, "model")
+        return assign * 2 + aggregator.unpack_bits(packed, n)
+    return fn
+
+def run(route_fn, mesh, binned, assign, dec):
+    body = lambda b, a, f, t: route_fn(
+        b, a, split.SplitDecision(feature=f, threshold=t, gain=None))
+    spec = P(None, "data")
+    prog = jax.jit(shard_map(
+        body, mesh=mesh, in_specs=(P("data", "model"), spec, P(), P()),
+        out_specs=spec, check_vma=False))
+    return np.asarray(prog(binned, assign, dec.feature, dec.threshold))
+
+parties, shards = map(int, sys.argv[1:3])
+mesh = make_vfl_mesh(parties, shards)
+rng = np.random.default_rng(parties * 10 + shards)
+B, d_party, n = 32, 5, 202
+d = parties * d_party
+out = []
+for T, width in [(1, 1), (2, 2), (5, 4), (3, 64)]:
+    binned = rng.integers(0, B, (n, d))
+    binned[: n // 8] = B - 1
+    binned[n // 8: n // 4] = 0
+    feature = rng.integers(0, d, (T, width))   # either party's features
+    threshold = rng.integers(0, B, (T, width))
+    threshold.flat[::2] = rng.choice([0, B - 1], threshold.flat[::2].shape)
+    unsplit = rng.random((T, width)) < 1 / 3
+    feature[unsplit], threshold[unsplit] = -1, B
+    dec = split.SplitDecision(feature=jnp.asarray(feature, jnp.int32),
+                              threshold=jnp.asarray(threshold, jnp.int32),
+                              gain=None)
+    binned = jnp.asarray(binned, jnp.int32)
+    assign = jnp.asarray(rng.integers(0, width, (T, n)), jnp.int32)
+    m_new, m_old = compress.MessageMeter(), compress.MessageMeter()
+    new = run(aggregator.federated_round_route_fn(meter=m_new), mesh,
+              binned, assign, dec)
+    old = run(per_row_gather_route_fn(m_old), mesh, binned, assign, dec)
+    owners = {int(f) // d_party for f in feature.flat if f >= 0}
+    out.append({"T": T, "width": width, "equal": bool((new == old).all()),
+                "meter_new": m_new.entries, "meter_old": m_old.entries,
+                "owners": sorted(owners), "unsplit": int(unsplit.sum()),
+                "right": int((new % 2).sum())})
+print(json.dumps(out))
+"""
+
+
+def _federated_route_cases(parties, shards):
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={parties * shards}")
+    env["PYTHONPATH"] = os.path.join(repo, "src")
+    proc = subprocess.run(
+        [sys.executable, "-c", ROUTE_PROBE, str(parties), str(shards)],
+        env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
 # ---------------------------------------------------------------------------
 # deterministic sweeps — always run (tier-1 container has no hypothesis)
 # ---------------------------------------------------------------------------
@@ -213,6 +306,22 @@ def test_pack_bits_psum_is_carry_free(parties, seed):
 @pytest.mark.parametrize("n,shards", [(1, 1), (701, 2), (1536, 4), (999, 8)])
 def test_wire_id_partition_shard_arithmetic(n, shards):
     _check_wire_arithmetic(n=n, shards=shards, depth=3)
+
+
+@pytest.mark.parametrize("parties,shards", [(2, 1), (2, 2)])
+def test_federated_route_matches_per_row_gather(parties, shards):
+    """The federated round route (row compares over each party's own
+    columns) gives the per-row gather formulation's next indices bit for
+    bit, with nodes split on either party's features and unsplit nodes,
+    and meters the same id_partition payload per level."""
+    for case in _federated_route_cases(parties, shards):
+        assert case["equal"], case
+        assert case["meter_new"] == case["meter_old"], case
+        assert len(case["meter_new"]) == 1, case  # one bitmap per level
+        # the case exercised both parties' features and unsplit nodes
+        if case["width"] >= 4:
+            assert case["owners"] == list(range(parties)), case
+            assert case["unsplit"] > 0 and case["right"] > 0, case
 
 
 # ---------------------------------------------------------------------------

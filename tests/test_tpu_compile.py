@@ -103,3 +103,30 @@ def test_serving_kernel_compiles(one_chip, compiled_for_tpu, raw):
         _aval((ENSEMBLE_TREES,), jnp.float32, one_chip),
         _aval((SERVE_ROWS, D), x_dtype, one_chip),
     )
+
+
+@pytest.mark.parametrize("width", [1, 2, 4])
+def test_round_route_has_no_per_row_gather(one_chip, width):
+    """Routing a depth-3 level compiles to whole-row reads: no gather of
+    single elements (``slice_sizes={1,1}``) is left, and the level counts
+    as routed by rows."""
+    import re
+
+    from repro.core import split, tree
+    from repro.obs import compiles
+
+    def route(binned, assign, feature, threshold):
+        return tree.route_local_round(binned, assign, split.SplitDecision(
+            feature=feature, threshold=threshold, gain=None))
+
+    rows = compiles.install().route_levels
+    before = rows.value
+    text = jax.jit(route).lower(
+        _aval((N, D), jnp.int32, one_chip),
+        _aval((TREES, N), jnp.int32, one_chip),
+        _aval((TREES, width), jnp.int32, one_chip),
+        _aval((TREES, width), jnp.int32, one_chip),
+    ).compile().as_text()
+    assert rows.value - before == 1
+    gathers = re.findall(r"= \S+ gather\(.*", text)
+    assert not [g for g in gathers if "slice_sizes={1,1}" in g], gathers

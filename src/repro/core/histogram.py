@@ -340,11 +340,20 @@ def derive_sibling(parent_hist: jnp.ndarray, left_hist: jnp.ndarray) -> jnp.ndar
       left_hist: (..., P, d, B, 3) — left-child histograms indexed by
         parent (``as_child_fn`` / ``as_round_child_fn``).
 
+    A derived bin that holds no rows (its count, the last stat channel, is
+    0 where the parent's is not) is set to exactly 0: otherwise it keeps the
+    rounding residue of every subtraction above it, which at depth 8 grows
+    past ``min_child_weight`` and lets a near-empty node split off an empty
+    child.  With 0/1 weights counts are integers, so the test is exact; a
+    transport that ships no counts (count 0 in the parent) is left as it is.
+
     Returns:
       (..., 2P, d, B, 3) with node ``2p`` = left child, ``2p + 1`` = derived
       right sibling, matching the routing order ``assign * 2 + go_right``.
     """
     right = parent_hist - left_hist
+    empty = (right[..., -1:] == 0) & (parent_hist[..., -1:] != 0)
+    right = jnp.where(empty, 0.0, right)
     *batch, p, d, b, s = left_hist.shape
     return jnp.stack([left_hist, right], axis=-4).reshape(
         *batch, 2 * p, d, b, s

@@ -37,6 +37,9 @@ from repro.core import split as split_mod
 from repro.core.types import PackedEnsemble, TreeArrays, TreeConfig
 from repro.obs import compiles as compiles_mod
 
+#: nodes of a level routed in one straight-line block (``level_go_right``)
+ROUTE_UNROLL = 8
+
 
 def traverse_level(
     binned: jnp.ndarray,
@@ -122,9 +125,15 @@ def level_go_right(
     loop over the level's nodes accumulates into one (T, n) array.  Per
     level on a TPU v5e at T 5, n 105,000, d 10 (``benchmarks/route_probe.py``):
     0.036 ms at width 2, 0.047 at 4, 0.24 at 64, 0.70 at 128, 4.49 at 256,
-    against 11.9-15.7 ms for per-row gathers at every width from 2 to 256.  The
-    node loop is unrolled, so compile time grows with the width (23 s at
-    256).  ``fedgbf_route_levels_total`` counts each traced level.
+    against 11.9-15.7 ms for per-row gathers at every width from 2 to 256.
+
+    The node loop is a ``fori_loop`` unrolled ``ROUTE_UNROLL`` nodes at a
+    time, so a level of up to ``ROUTE_UNROLL`` nodes is straight-line code
+    and a wider one a loop of such blocks: compile time stops growing with
+    the width.  Fully unrolled, a depth-8 round (T 5, n 700,000, d 28, B 256)
+    compiled for a v5e in 111 s, 83 s of it the routing of its 255 nodes;
+    in blocks of 8, in 28 s.  ``fedgbf_route_levels_total`` counts each
+    traced level.
 
     Args:
       binned: (n, d) int32 binned features.
@@ -138,12 +147,17 @@ def level_go_right(
         counter.route_levels.inc()
     cols = binned.T                               # (d, n) feature-major
     f_safe = jnp.clip(feature, 0, None)
-    go_right = jnp.zeros(idx.shape, bool)
-    for j in range(feature.shape[1]):
-        right = ((feature[:, j, None] >= 0)
-                 & (cols[f_safe[:, j]] > threshold[:, j, None]))  # (T, n)
-        go_right = go_right | ((idx == j) & right)
-    return go_right
+    width = feature.shape[1]
+
+    def node(j, go_right):
+        f = jax.lax.dynamic_index_in_dim(feature, j, 1, keepdims=False)
+        fs = jax.lax.dynamic_index_in_dim(f_safe, j, 1, keepdims=False)
+        t = jax.lax.dynamic_index_in_dim(threshold, j, 1, keepdims=False)
+        right = (f[:, None] >= 0) & (cols[fs] > t[:, None])   # (T, n)
+        return go_right | ((idx == j) & right)
+
+    return jax.lax.fori_loop(0, width, node, jnp.zeros(idx.shape, bool),
+                             unroll=min(width, ROUTE_UNROLL))
 
 
 def route_local_round(binned, assign, decision) -> jnp.ndarray:

@@ -1,4 +1,4 @@
-"""Jitted wrappers for the Pallas histogram kernel (``train_histogram.py``).
+"""Jitted wrappers for the Pallas histogram kernels (``train_histogram.py``).
 
 Drop-in replacements for the ``core.histogram`` providers:
 
@@ -13,7 +13,13 @@ Drop-in replacements for the ``core.histogram`` providers:
   accumulates the whole round's (T, nodes, d, B, 2K+1) histogram with the
   tree-invariant operands (binned, g, h) shared across the tree grid.
 
-The per-tree providers are the round kernel at T = 1, so the round and
+Two kernel forms serve them, chosen by the level's static width
+(``histogram_form``): the flat kernel (node x bin ids on lanes) for narrow
+frontiers, the wide-frontier form (nodes on the MXU's rows, a node-block
+grid axis, trees stacked on the rows, VMEM bounded whatever the width)
+beyond ``FLAT_MAX_LANES``.
+
+The per-tree providers are the round kernels at T = 1, so the round and
 per-tree paths are bit-identical by construction.  The wrappers do the
 layout (samples on lanes, tile padding) and undo it on the result.  The
 kernel runs compiled on TPU and in interpret mode on every other backend.
@@ -27,7 +33,18 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import interpret_mode
-from repro.kernels.histogram.train_histogram import histogram_pallas_call
+from repro.kernels.histogram.train_histogram import (
+    histogram_nodes_pallas_call,
+    histogram_pallas_call,
+    node_block,
+)
+from repro.obs import compiles as compiles_mod
+
+#: widest fused node x bin width the flat kernel serves: the credit
+#: configurations' widest level (4 nodes x B 32), which stays on it; wider
+#: levels take the wide-frontier form (``benchmarks/hist_form_probe.py`` has
+#: times by width)
+FLAT_MAX_LANES = 128
 
 
 def _round_up(x: int, m: int) -> int:
@@ -48,13 +65,14 @@ def _chan_rows(v: jnp.ndarray, pad_n: int) -> jnp.ndarray:
     return jnp.pad(v, ((0, 0), (0, pad_n)))
 
 
-@partial(
-    jax.jit,
-    static_argnames=(
-        "num_nodes", "num_bins", "tile_n", "feat_block", "child",
-        "root_delta_rows", "level",
-    ),
-)
+def histogram_form(num_nodes: int, num_bins: int) -> str:
+    """The kernel form for a level: ``"flat"`` (node x bin ids on lanes)
+    while the fused width ``num_nodes * num_bins`` is at most
+    ``FLAT_MAX_LANES``, else ``"nodes"`` (nodes on the MXU's rows, a
+    node-block grid axis)."""
+    return "flat" if num_nodes * num_bins <= FLAT_MAX_LANES else "nodes"
+
+
 def compute_round_histogram_pallas(
     binned: jnp.ndarray,
     g: jnp.ndarray,
@@ -71,9 +89,13 @@ def compute_round_histogram_pallas(
     level: int = 0,
 ) -> jnp.ndarray:
     """Round-native provider (``core.histogram.compute_round_histogram``
-    contract) served by the tree-grid kernel: ONE launch accumulates all T
+    contract) served by the tree-grid kernels: ONE launch accumulates all T
     trees' histograms.  Only tile-boundary zero padding happens in XLA
     (padded samples carry weight 0, so they accumulate nothing).
+
+    The form follows the static width (``histogram_form``); each call is
+    counted in ``fedgbf_hist_levels_total{form=...}`` at trace time while
+    the compile counter is installed.
 
     With ``child=True`` it is the subtraction pipeline's round child
     provider (``assign`` is the current level's assignment, ``num_nodes``
@@ -94,10 +116,27 @@ def compute_round_histogram_pallas(
             binned, g, h, weight, num_bins, root_delta_rows,
             base_tree_fn=compute_histogram_pallas,
         )
+    form = histogram_form(num_nodes, num_bins)
+    counter = compiles_mod.installed()
+    if counter is not None:
+        counter.hist_levels[form].inc()
+    return _round_histogram(
+        binned, g, h, weight, assign, num_nodes, num_bins, form=form,
+        tile_n=tile_n, feat_block=feat_block, child=child,
+    )
+
+
+@partial(
+    jax.jit,
+    static_argnames=(
+        "num_nodes", "num_bins", "form", "tile_n", "feat_block", "child",
+    ),
+)
+def _round_histogram(binned, g, h, weight, assign, num_nodes, num_bins, *,
+                     form, tile_n, feat_block, child):
+    """Layout in, one kernel launch, layout out."""
     n, d = binned.shape
     t = weight.shape[0]
-    nb = num_nodes * num_bins
-    nb_pad = _round_up(nb, 128)  # one-hot width on lanes
     stats = _num_stats(g)
 
     n_pad = _round_up(n, tile_n)
@@ -105,18 +144,31 @@ def compute_round_histogram_pallas(
     pad_n = n_pad - n
     binned_t = jnp.pad(binned.T, ((0, d_pad - d), (0, pad_n)))
     tree_rows = lambda v: jnp.pad(v, ((0, 0), (0, pad_n)))[:, None, :]
+    operands = (binned_t, tree_rows(assign), _chan_rows(g, pad_n),
+                _chan_rows(h, pad_n), tree_rows(weight.astype(jnp.float32)))
+    kw = dict(tile_n=tile_n, feat_block=feat_block,
+              interpret=interpret_mode(), child_mode=child)
 
-    hist = histogram_pallas_call(
-        binned_t, tree_rows(assign), _chan_rows(g, pad_n), _chan_rows(h, pad_n),
-        tree_rows(weight.astype(jnp.float32)), nb_pad, num_bins,
-        tile_n=tile_n, feat_block=feat_block, interpret=interpret_mode(),
-        child_mode=child,
-    )  # (T, d_pad, stats_pad, nb_pad)
+    if form == "flat":
+        nb = num_nodes * num_bins
+        hist = histogram_pallas_call(
+            *operands, _round_up(nb, 128), num_bins, **kw
+        )  # (T, d_pad, stats_pad, nb_pad)
+        hist = hist[:, :d, :stats, :nb]
+        return hist.reshape(t, d, stats, num_nodes, num_bins).transpose(
+            0, 3, 1, 4, 2
+        )
 
-    hist = hist[:, :d, :stats, :nb]
-    return hist.reshape(t, d, stats, num_nodes, num_bins).transpose(
-        0, 3, 1, 4, 2
-    )
+    hist = histogram_nodes_pallas_call(
+        *operands, num_nodes, _round_up(num_bins, 128), **kw
+    )  # (n_blocks, d_pad, rows, b_pad)
+    n_blocks = hist.shape[0]
+    block = node_block(num_nodes, t * stats)
+    hist = hist[:, :d, :t * stats * block, :num_bins]
+    hist = hist.reshape(n_blocks, d, t, stats, block, num_bins).transpose(
+        2, 0, 4, 1, 5, 3
+    )  # (T, n_blocks, block, d, B, stats)
+    return hist.reshape(t, n_blocks * block, d, num_bins, stats)[:, :num_nodes]
 
 
 def compute_round_histogram_pallas_child(

@@ -20,6 +20,11 @@ that keeps them in ``MetricsRegistry`` counters:
   while the counter is installed.  It counts at trace time, so a program
   loaded from the persistent cache adds nothing, and costs the device
   nothing.
+* ``fedgbf_hist_levels_total{form="flat"|"nodes"}`` — histogram passes
+  traced into a program by the Pallas round provider
+  (``kernels.histogram.ops.compute_round_histogram_pallas``), by the
+  kernel form its width chose; counted at trace time like the route
+  levels.
 
 A repeat call of a compiled program fires none of these, so a counter that
 moves during live serving is a compile the batch ladder claims never
@@ -35,7 +40,7 @@ the one counter and installs it once.
 
 from __future__ import annotations
 
-from repro.obs.metrics import MetricsRegistry
+from repro.obs.metrics import MetricsRegistry, _label_str
 from repro.obs.trace import annotation
 
 TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
@@ -46,6 +51,8 @@ CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 #: the name a built program leaves in a profiler capture
 MARK = "compile"
+#: the Pallas histogram kernel forms (``kernels.histogram.ops``)
+HIST_FORMS = ("flat", "nodes")
 
 
 class CompileCounter:
@@ -77,6 +84,12 @@ class CompileCounter:
             "fedgbf_route_levels_total",
             "Tree levels traced into a round-routing program, routed by "
             "whole-row compares.", labels={"form": "rows"})
+        self.hist_levels = {
+            form: r.counter(
+                "fedgbf_hist_levels_total",
+                "Histogram passes traced into a program by the Pallas round "
+                "provider, by kernel form.", labels={"form": form})
+            for form in HIST_FORMS}
         self._durations = {TRACE_EVENT: self.trace_seconds,
                            CACHE_LOAD_EVENT: self.cache_load_seconds}
         self._events = {CACHE_HIT_EVENT: self.cache_hits,
@@ -86,11 +99,12 @@ class CompileCounter:
     def instruments(self) -> tuple:
         return (self.compiles, self.compile_seconds, self.trace_seconds,
                 self.cache_hits, self.cache_misses, self.cache_load_seconds,
-                self.route_levels)
+                self.route_levels, *self.hist_levels.values())
 
     def snapshot(self) -> dict:
-        """Every counter's value, by metric name."""
-        return {m.name: m.value for m in self.instruments}
+        """Every counter's value, by series (name and labels)."""
+        return {m.name + _label_str(m.labels): m.value
+                for m in self.instruments}
 
     def on_duration(self, event: str, seconds: float, **_kw) -> None:
         if event == COMPILE_EVENT:

@@ -239,6 +239,143 @@ def test_round_child_kernel_matches_adapted_ref():
 
 
 # ---------------------------------------------------------------------------
+# wide-frontier form (nodes on the MXU's rows, node-block grid axis)
+# ---------------------------------------------------------------------------
+from repro.kernels.histogram import ops as _hist_ops  # noqa: E402
+
+
+def _onehot_round_ref(binned, g, h, w, assign, nodes, B, child):
+    """``core.histogram``'s one-hot reference per tree, with the child
+    pipeline's left mask and halved ids applied outside it."""
+    from repro.core.histogram import compute_histogram_onehot
+
+    if child:
+        w = w * (1 - assign % 2).astype(w.dtype)
+        assign = assign // 2
+    return jax.vmap(lambda wt, at: compute_histogram_onehot(
+        binned, g, h, wt, at, nodes, B))(w, assign)
+
+
+@pytest.mark.parametrize("child", [False, True])
+@pytest.mark.parametrize("B", [32, 256])
+@pytest.mark.parametrize("nodes,T", [(1, 1), (8, 3), (64, 1), (64, 3)])
+def test_nodes_form_matches_onehot_ref(nodes, T, B, child):
+    """The wide-frontier form against the one-hot reference at frontier
+    widths inside one node block (1, 8) and at a full block (64), plain
+    and child, with one tree and with several."""
+    rng = np.random.default_rng(nodes * 7 + T + B + child)
+    n, d = 600, 5
+    binned = jnp.asarray(rng.integers(0, B, (n, d)), jnp.int32)
+    g = jnp.asarray(rng.normal(size=n), jnp.float32)
+    h = jnp.asarray(rng.random(n) + 0.05, jnp.float32)
+    w = jnp.asarray(rng.integers(0, 2, (T, n)), jnp.float32)
+    assign = jnp.asarray(
+        rng.integers(0, (2 if child else 1) * nodes, (T, n)), jnp.int32)
+    out = _hist_ops._round_histogram(
+        binned, g, h, w, assign, nodes, B, form="nodes", tile_n=512,
+        feat_block=8, child=child)
+    ref = _onehot_round_ref(binned, g, h, w, assign, nodes, B, child)
+    assert out.shape == (T, nodes, d, B, 3)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5
+    )
+
+
+@pytest.mark.parametrize("nodes,child", [(9, False), (70, True)])
+def test_nodes_form_k_channels_and_several_node_blocks(nodes, child):
+    """K = 3 gradient channels over T 2 trees (14 rows a node, 16 nodes a
+    block), so 9 nodes fit one block and 70 parents span five, the last
+    one partly padding."""
+    rng = np.random.default_rng(nodes)
+    n, d, B, T, K = 700, 3, 16, 2, 3
+    binned = jnp.asarray(rng.integers(0, B, (n, d)), jnp.int32)
+    g = jnp.asarray(rng.normal(size=(n, K)), jnp.float32)
+    h = jnp.asarray(rng.random((n, K)) + 0.05, jnp.float32)
+    w = jnp.asarray(rng.integers(0, 2, (T, n)), jnp.float32)
+    assign = jnp.asarray(
+        rng.integers(0, (2 if child else 1) * nodes, (T, n)), jnp.int32)
+    out = _hist_ops._round_histogram(
+        binned, g, h, w, assign, nodes, B, form="nodes", tile_n=256,
+        feat_block=8, child=child)
+    ref = _onehot_round_ref(binned, g, h, w, assign, nodes, B, child)
+    assert out.shape == (T, nodes, d, B, 2 * K + 1)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5
+    )
+
+
+def test_bf16_split_is_exact_and_its_sums_match_highest():
+    """The wide-frontier form's three bf16 parts add back to each float32
+    operand exactly, and its one-pass sums match the same contraction at
+    ``Precision.HIGHEST`` to float32 reassociation."""
+    from repro.kernels.histogram.train_histogram import split_bf16
+
+    rng = np.random.default_rng(31)
+    x = jnp.asarray(np.concatenate([
+        rng.normal(size=4000), rng.normal(size=4000) * 1e-3,
+        rng.random(4000) * 0.25, np.ldexp(rng.random(200), -60),
+        np.ldexp(rng.random(200), 60), [0.0, 1.0, -1.0]]), jnp.float32)
+    hi, mid, lo = split_bf16(x)
+    assert hi.dtype == mid.dtype == lo.dtype == jnp.bfloat16
+    back = (hi.astype(jnp.float32) + mid.astype(jnp.float32)
+            + lo.astype(jnp.float32))
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(x))
+
+    n, d, B, nodes, T = 900, 4, 256, 8, 3
+    binned = jnp.asarray(rng.integers(0, B, (n, d)), jnp.int32)
+    g = jnp.asarray(rng.normal(size=n), jnp.float32)
+    h = jnp.asarray(rng.random(n) * 0.25, jnp.float32)
+    w = jnp.asarray(rng.integers(0, 2, (T, n)), jnp.float32)
+    assign = jnp.asarray(rng.integers(0, nodes, (T, n)), jnp.int32)
+    out = _hist_ops._round_histogram(
+        binned, g, h, w, assign, nodes, B, form="nodes", tile_n=512,
+        feat_block=8, child=False)
+    with jax.default_matmul_precision("highest"):
+        ref = _onehot_round_ref(binned, g, h, w, assign, nodes, B, False)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), rtol=1e-6, atol=1e-6
+    )
+
+
+@pytest.mark.parametrize("nodes,B", [(1, 32), (2, 32), (4, 32), (1, 16)])
+def test_credit_widths_stay_on_the_flat_kernel(nodes, B):
+    """Every level width of the depth-3, B-32 credit cells (nodes 1/2/4,
+    child parents 1/2) keeps today's flat kernel."""
+    assert _hist_ops.histogram_form(nodes, B) == "flat"
+
+
+@pytest.mark.parametrize("nodes", [1, 2, 8, 64, 128])
+def test_wide_levels_take_the_nodes_form(nodes):
+    """A 256-bin level is wider than the flat kernel's limit at any
+    frontier, so every depth-8 HIGGS level takes the nodes form."""
+    assert nodes * 256 > _hist_ops.FLAT_MAX_LANES
+    assert _hist_ops.histogram_form(nodes, 256) == "nodes"
+
+
+def test_hist_levels_counter_counts_each_call_by_form():
+    """``fedgbf_hist_levels_total{form=...}`` counts a provider call at
+    trace time, by the form its width chose, repeat calls included."""
+    from repro.obs import compiles
+
+    levels = compiles.install().hist_levels
+    rng = np.random.default_rng(5)
+    n, d = 300, 3
+    binned = jnp.asarray(rng.integers(0, 256, (n, d)), jnp.int32)
+    g = jnp.asarray(rng.normal(size=n), jnp.float32)
+    h = jnp.ones(n, jnp.float32)
+    w = jnp.ones((1, n), jnp.float32)
+    a = jnp.zeros((1, n), jnp.int32)
+    before = {f: c.value for f, c in levels.items()}
+    compute_round_histogram_pallas(binned, g, h, w, a, 2, 32)
+    compute_round_histogram_pallas(binned, g, h, w, a, 2, 32)
+    compute_round_histogram_pallas(binned, g, h, w, a, 1, 256)
+    assert levels["flat"].value - before["flat"] == 2
+    assert levels["nodes"].value - before["nodes"] == 1
+    snap = compiles.installed().snapshot()
+    assert 'fedgbf_hist_levels_total{form="nodes"}' in snap
+
+
+# ---------------------------------------------------------------------------
 # ensemble_predict kernel
 # ---------------------------------------------------------------------------
 import repro.core.forest as _forest

@@ -77,6 +77,31 @@ def test_build_round_bit_identical_to_per_tree_vmap(backend, subtraction):
     np.testing.assert_array_equal(np.asarray(assign_r), np.asarray(assign_v))
 
 
+@pytest.mark.parametrize("max_depth", [6, 8])
+def test_local_pallas_deep_256_bin_round_matches_local(max_depth):
+    """Depth 6-8 at B 256 (the HIGGS shape's levels, every one wider than
+    the flat kernel's limit): the Pallas round providers choose the same
+    features and thresholds as the segment-sum ``local`` path, with leaves
+    within float32 tolerance.
+
+    g and h are multiples of 1/64, so every histogram sum is exact in
+    float32 in any order: the two paths' histograms are equal, and a split
+    can differ only by a fault, not by a near-tie rounded two ways."""
+    binned, g, h, smask, fmask = _case(seed=3, n=900, d=5, B=256, T=2,
+                                       rho=0.9)
+    rng = np.random.default_rng(4)
+    g = jnp.asarray(rng.integers(-64, 65, g.shape) / 64.0, jnp.float32)
+    h = jnp.asarray(rng.integers(1, 65, h.shape) / 64.0, jnp.float32)
+    cfg = TreeConfig(max_depth=max_depth, num_bins=256, hist_subtraction=True)
+    trees_p, assign_p = tree.build_round(
+        binned, g, h, smask, fmask, cfg, backend=get_backend("local-pallas"))
+    trees_l, assign_l = tree.build_round(
+        binned, g, h, smask, fmask, cfg, backend=get_backend("local"))
+    assert int(np.sum(np.asarray(trees_l.feature) >= 0)) > 2 ** (max_depth - 1)
+    _assert_trees_equal(trees_p, trees_l, leaf_tol=1e-5)
+    np.testing.assert_array_equal(np.asarray(assign_p), np.asarray(assign_l))
+
+
 def test_build_tree_is_t1_special_case():
     """``build_tree`` delegates to the round engine with a singleton tree
     axis — same arrays, no leading dim."""

@@ -230,3 +230,56 @@ def test_masks_compose_with_subtraction():
     np.testing.assert_allclose(
         np.asarray(pred_d), np.asarray(pred_s), rtol=1e-5, atol=1e-6
     )
+
+
+def test_derive_sibling_zeroes_empty_bins_and_leaves_countless_payloads():
+    """A derived bin with no rows is exactly 0, not the parent's rounding
+    residue; a payload that carries no counts (count 0 in the parent, as
+    the quantized transports ship) is derived as plain ``parent - left``."""
+    rng = np.random.default_rng(3)
+    parent = jnp.asarray(rng.random((2, 3, 4, 3)) * 100.0, jnp.float32)
+    parent = parent.at[..., -1].set(jnp.asarray(rng.integers(1, 9, (2, 3, 4)),
+                                                jnp.float32))
+    # left takes every row of bin 0 (same count), its sums rounded apart
+    left = (parent * 0.5).at[:, :, 0, :].set(parent[:, :, 0, :] * (1 + 1e-7))
+    left = left.at[:, :, 0, -1].set(parent[:, :, 0, -1])
+    right = derive_sibling(parent, left).reshape(2, 2, 3, 4, 3)[:, 1]
+    np.testing.assert_array_equal(np.asarray(right[:, :, 0, :]), 0.0)
+    np.testing.assert_array_equal(np.asarray(right[:, :, 1:, :]),
+                                  np.asarray((parent - left)[:, :, 1:, :]))
+    countless = parent.at[..., -1].set(0.0)
+    left_c = left.at[..., -1].set(0.0)
+    np.testing.assert_array_equal(
+        np.asarray(derive_sibling(countless, left_c).reshape(2, 2, 3, 4, 3)[:, 1]),
+        np.asarray(countless - left_c))
+
+
+@pytest.mark.parametrize("backend", ["local", "local-pallas"])
+def test_deep_subtraction_never_splits_off_an_empty_child(backend):
+    """Depth 8, B 256, large root sums and a tiny ``min_child_weight``: the
+    rounding residue that subtraction carries down would pass the weight
+    test in empty bins; with empty derived bins zeroed, every split keeps
+    sampled rows on both sides."""
+    from repro.core.backend import get_backend
+
+    binned, g, h, _, _ = _case(0, 3000, 5, 256, 1)
+    rng = np.random.default_rng(1)
+    smask = jnp.asarray(rng.random((2, 3000)) < 0.9, jnp.float32)
+    fmask = jnp.ones((2, 5), bool)
+    cfg = TreeConfig(max_depth=8, num_bins=256, hist_subtraction=True,
+                     min_child_weight=1e-6)
+    trees, _ = tree.build_round(binned, g * 100.0, h * 100.0, smask, fmask,
+                                cfg, backend=get_backend(backend))
+    b = np.asarray(binned)
+    for t in range(2):
+        rows = np.flatnonzero(np.asarray(smask[t]) > 0)
+        feat, thr = np.asarray(trees.feature[t]), np.asarray(trees.threshold[t])
+        idx = np.zeros(rows.size, np.int64)
+        for level in range(8):
+            off = 2 ** level - 1
+            f, th = feat[off + idx], thr[off + idx]
+            right = (f >= 0) & (b[rows, np.clip(f, 0, None)] > th)
+            for k in np.flatnonzero(feat[off:off + 2 ** level] >= 0):
+                side = right[idx == k]
+                assert side.any() and not side.all(), (t, level, k)
+            idx = idx * 2 + right

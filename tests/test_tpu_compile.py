@@ -7,7 +7,8 @@ Nothing runs, so they say nothing about results or speed.
 
 Shapes are the paper's Give-Me-Some-Credit cell: 105k training rows x 10
 features, B = 32 bins, depth 3 (frontier 1/2/4 nodes), a 5-tree round,
-and the 81-tree ensemble the 20-round dynamic 5->2 schedule packs.
+and the 81-tree ensemble the 20-round dynamic 5->2 schedule packs; and the
+HIGGS cell's widest histogram levels (700k x 28, B 256, 64 and 128 nodes).
 
 The topology is described inside a module fixture (never at import), so
 every xdist worker collects the same tests and only the one that runs
@@ -84,6 +85,31 @@ def test_round_histogram_kernel_compiles(one_chip, compiled_for_tpu, nodes,
         _aval((TREES, N), jnp.float32, one_chip),
         _aval((TREES, N), jnp.int32, one_chip),
     )
+
+
+# The HIGGS cell: 700k training rows x 28 features, B 256, depth 8.  The
+# flat kernel's one-hot at 64 parents (16,384 lanes) overruns VMEM; the
+# wide-frontier form holds one node block whatever the width.
+@pytest.mark.parametrize("nodes,child", [(64, True), (128, False)])
+def test_wide_frontier_histogram_compiles(one_chip, compiled_for_tpu, nodes,
+                                          child):
+    from repro.kernels.histogram.ops import (compute_round_histogram_pallas,
+                                             histogram_form)
+
+    n, d, b = 700_000, 28, 256
+    assert histogram_form(nodes, b) == "nodes"
+    fn = lambda binned, g, h, w, a: compute_round_histogram_pallas(
+        binned, g, h, w, a, nodes, b, child=child
+    )
+    text = compiled_for_tpu(
+        fn,
+        _aval((n, d), jnp.int32, one_chip),
+        _aval((n,), jnp.float32, one_chip),
+        _aval((n,), jnp.float32, one_chip),
+        _aval((TREES, n), jnp.float32, one_chip),
+        _aval((TREES, n), jnp.int32, one_chip),
+    )
+    assert "fedgbf_histogram_nodes" in text
 
 
 @pytest.mark.parametrize("raw", [True, False])

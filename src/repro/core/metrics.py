@@ -2,22 +2,43 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+from repro.obs import compiles as compiles_mod
 
 
 def auc(y: jnp.ndarray, score: jnp.ndarray) -> jnp.ndarray:
-    """Area under ROC via the Mann-Whitney rank statistic (ties averaged)."""
-    y = y.astype(jnp.float32)
-    n = y.shape[0]
-    order = jnp.argsort(score)
-    sorted_scores = score[order]
-    # average ranks for ties: rank = mean of 1-based positions of equal scores
-    ranks_lo = jnp.searchsorted(sorted_scores, score, side="left").astype(jnp.float32)
-    ranks_hi = jnp.searchsorted(sorted_scores, score, side="right").astype(jnp.float32)
-    ranks = 0.5 * (ranks_lo + ranks_hi + 1.0)  # 1-based average rank
-    n_pos = jnp.sum(y)
+    """Area under ROC via the Mann-Whitney rank statistic (ties averaged).
+
+    One sort of ``(score, y)`` on the score carries the labels along; the
+    rest is elementwise passes and prefix scans, with no gather, scatter
+    or binary search.  Equal neighbours in the sorted scores form a tie
+    group; ``-0.0`` and ``+0.0`` compare equal, so they share a group.  A
+    position's group starts at the running max of the group-start indices
+    and ends at the reversed running min of the group-end indices; its
+    1-based average rank is ``(start + end) / 2 + 1``.  The positions are
+    int32 and exact; the ranks (exact in float32 below 2**23 rows) are
+    summed over the positives in float32, as the statistic always was.
+    A single-class ``y`` gives 0: the pair count is guarded to at least 1.
+    """
+    counter = compiles_mod.installed()
+    if counter is not None:
+        counter.auc_traces.inc()
+    n = score.shape[0]
+    # Order within a tie leaves the rank sum as it is; a stable sort would
+    # carry an iota as a third operand on the TPU.
+    s, ys = jax.lax.sort((score, y.astype(jnp.float32)), num_keys=1,
+                         is_stable=False)
+    pos = jax.lax.iota(jnp.int32, n)
+    new = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]])
+    last = jnp.concatenate([new[1:], jnp.ones((1,), bool)])
+    start = jax.lax.cummax(jnp.where(new, pos, 0))
+    end = jax.lax.cummin(jnp.where(last, pos, n - 1), reverse=True)
+    ranks = 0.5 * (start + end).astype(jnp.float32) + 1.0
+    n_pos = jnp.sum(ys)
     n_neg = n - n_pos
-    sum_pos_ranks = jnp.sum(ranks * y)
+    sum_pos_ranks = jnp.sum(ranks * ys)
     return (sum_pos_ranks - n_pos * (n_pos + 1.0) / 2.0) / jnp.maximum(n_pos * n_neg, 1.0)
 
 

@@ -24,7 +24,10 @@ that keeps them in ``MetricsRegistry`` counters:
   traced into a program by the Pallas round provider
   (``kernels.histogram.ops.compute_round_histogram_pallas``), by the
   kernel form its width chose; counted at trace time like the route
-  levels.
+  levels;
+* ``fedgbf_auc_traces_total{form="sorted_scan"}`` — AUCs traced into
+  a program by ``core.metrics.auc`` (one sort, then prefix scans); counted
+  at trace time like the route levels.
 
 A repeat call of a compiled program fires none of these, so a counter that
 moves during live serving is a compile the batch ladder claims never
@@ -90,6 +93,10 @@ class CompileCounter:
                 "Histogram passes traced into a program by the Pallas round "
                 "provider, by kernel form.", labels={"form": form})
             for form in HIST_FORMS}
+        self.auc_traces = r.counter(
+            "fedgbf_auc_traces_total",
+            "AUC computations traced into a program, ranked by one sort and "
+            "prefix scans.", labels={"form": "sorted_scan"})
         self._durations = {TRACE_EVENT: self.trace_seconds,
                            CACHE_LOAD_EVENT: self.cache_load_seconds}
         self._events = {CACHE_HIT_EVENT: self.cache_hits,
@@ -99,7 +106,8 @@ class CompileCounter:
     def instruments(self) -> tuple:
         return (self.compiles, self.compile_seconds, self.trace_seconds,
                 self.cache_hits, self.cache_misses, self.cache_load_seconds,
-                self.route_levels, *self.hist_levels.values())
+                self.route_levels, *self.hist_levels.values(),
+                self.auc_traces)
 
     def snapshot(self) -> dict:
         """Every counter's value, by series (name and labels)."""

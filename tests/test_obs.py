@@ -391,3 +391,24 @@ def test_compile_counter_counts_a_fresh_jit_once_and_a_repeat_never():
     assert "# TYPE fedgbf_compiles_total counter" in text
     assert f"fedgbf_compiles_total {int(again['fedgbf_compiles_total'])}" \
         in text
+
+
+def test_auc_trace_is_counted_once_and_a_repeat_never():
+    """One AUC traced into a program adds 1 to
+    ``fedgbf_auc_traces_total{form="sorted_scan"}``; a repeat call of the
+    compiled program adds nothing."""
+    from repro.core import metrics
+    from repro.obs import compiles
+
+    counter = compiles.install()
+    y = jnp.asarray([0.0, 1.0, 1.0, 0.0, 1.0])
+    s = jnp.asarray([0.1, 0.9, 0.4, 0.4, 0.7])
+    before = counter.auc_traces.value
+    f = jax.jit(lambda a, b: metrics.auc(a, b))  # a new function: one trace
+    first = float(f(y, s))
+    assert counter.auc_traces.value - before == 1
+    assert float(f(y, s)) == first
+    assert counter.auc_traces.value - before == 1
+    snap = counter.snapshot()
+    assert snap['fedgbf_auc_traces_total{form="sorted_scan"}'] == \
+        counter.auc_traces.value

@@ -156,3 +156,19 @@ def test_round_route_has_no_per_row_gather(one_chip, width):
     assert rows.value - before == 1
     gathers = re.findall(r"= \S+ gather\(.*", text)
     assert not [g for g in gathers if "slice_sizes={1,1}" in g], gathers
+
+
+def test_train_auc_compiles_to_one_sort_and_no_loop(one_chip):
+    """The train AUC at the GMSC cell's rows compiles for the chip to one
+    two-operand sort and prefix scans: no gather, scatter or while loop."""
+    import re
+
+    from repro.core import metrics
+
+    text = jax.jit(metrics.auc).lower(
+        _aval((N,), jnp.float32, one_chip), _aval((N,), jnp.float32, one_chip)
+    ).compile().as_text()
+    for op in ("gather", "scatter", "while"):
+        assert not re.search(rf"= \S+ {op}\(", text), op
+    sorts = re.findall(r"= \((.*?)\) sort\(", text)
+    assert len(sorts) == 1 and sorts[0].count("[") == 2, sorts
